@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import timeit, write_bench_json
+from repro.launch.compile_cache import enable_compile_cache
 
 #: gated int8 fused_mlp must beat f32 rows/s by at least this factor on
 #: >= 1 served shape (HBM-bound regime: weights quarter, io unchanged)
@@ -109,7 +110,7 @@ def kernel_bench(fast=False):
     # interpret-mode validation deltas (correctness, not speed)
     from repro.kernels.flash_attention.flash_attention import flash_attention
     a = flash_attention(q[:, :64], k[:, :64], v[:, :64], causal=True,
-                        block_q=32, block_k=32)
+                        block_q=32, block_k=32, interpret=True)
     b = flash_attention_ref(q[:, :64], k[:, :64], v[:, :64], causal=True)
     rows.append(("kernel/flash_interpret_maxerr", 0.0,
                  f"err={float(jnp.abs(a-b).max()):.2e}"))
@@ -358,6 +359,7 @@ def quant_check(fast=False, markdown=False):
 
 
 def main(argv=None):
+    enable_compile_cache()
     import argparse
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--fast", action="store_true")

@@ -165,6 +165,8 @@ def _markdown(res):
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--check", action="store_true",
                     help="fail unless every host serves bit-identically and "

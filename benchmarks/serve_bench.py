@@ -33,6 +33,7 @@ import jax
 import numpy as np
 
 from benchmarks.common import write_bench_json
+from repro.launch.compile_cache import enable_compile_cache
 
 CHECK_SPEEDUP = 3.0
 #: instrumentation gate: tracing ON must keep >= this fraction of the
@@ -1029,6 +1030,7 @@ def _markdown(rows, model_err):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--check", action="store_true",
                     help=f"fail unless coalesced >= {CHECK_SPEEDUP}x per-call"

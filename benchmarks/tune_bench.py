@@ -41,6 +41,7 @@ import jax
 import numpy as np
 
 from benchmarks.common import write_bench_json
+from repro.launch.compile_cache import enable_compile_cache
 
 CHECK_RATIO = 0.9        # adaptive rows/s vs best static
 MEASURED_BURST_RATIO = 0.85   # closed-loop rows/s vs open-loop (median)
@@ -288,6 +289,7 @@ def _markdown(krows, results):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--check", action="store_true",
                     help="fail unless every tuned kernel >= 1.0x default, "

@@ -7,6 +7,7 @@ import pathlib
 import tempfile
 
 from repro.apps import ALL_APPS
+from repro.launch.compile_cache import enable_compile_cache
 from repro.nas.nested import best_trial, nested_search, save_trial
 
 
@@ -30,6 +31,7 @@ def collect(app_name, app, n, db_path):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--app", default="binomial", choices=list(ALL_APPS))
     ap.add_argument("--n", type=int, default=1024)

@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import SurrogateDB, approx_ml, tensor_functor
+from repro.launch.compile_cache import enable_compile_cache
 from repro.nas.train_surrogate import fit
 from repro.nn import MLP
 from repro.nn.serialize import save_model
@@ -33,6 +34,7 @@ def smooth_step(t):
 
 
 def main():
+    enable_compile_cache()
     tmp = pathlib.Path(tempfile.mkdtemp())
     t = jax.random.normal(jax.random.PRNGKey(0), (N, M))
 

@@ -12,10 +12,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import LayerSpec, ModelConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm
 
 
 def main():
+    enable_compile_cache()
     cfg = ModelConfig(name="serve-demo", n_layers=4, d_model=256, n_heads=8,
                       n_kv_heads=4, head_dim=32, d_ff=1024, vocab_size=8192,
                       pattern=(LayerSpec(),))

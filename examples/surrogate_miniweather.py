@@ -12,10 +12,12 @@ import jax
 import numpy as np
 
 from repro.apps import miniweather as mw
+from repro.launch.compile_cache import enable_compile_cache
 from repro.nas.nested import best_trial, nested_search, save_trial
 
 
 def main():
+    enable_compile_cache()
     tmp = pathlib.Path(tempfile.mkdtemp())
     state = mw.init_state()
 

@@ -22,6 +22,7 @@ import numpy as np
 from repro.ckpt.checkpoint import CheckpointManager
 from repro.configs.base import LayerSpec, ModelConfig
 from repro.data.pipeline import TokenPipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train import trainer
 from repro.train.compression import ef_compress, init_residual, wire_bytes
 
@@ -34,6 +35,7 @@ PRESETS = {
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", default="20m", choices=list(PRESETS))
     ap.add_argument("--steps", type=int, default=200)

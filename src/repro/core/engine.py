@@ -162,7 +162,9 @@ class InferenceEngine:
         interpret/oracle mode); ``never``/``0`` pins f32.  In every mode
         except ``never`` the bundle must have **passed its accuracy
         gate** — a gate-fail (or stale/absent) verdict serves f32 even
-        under ``force``; that is the fail-safe the gate exists for.
+        under ``force``; that is the fail-safe the gate exists for.  An
+        error while reading the verdict propagates: it is a fault, not a
+        verdict.
         """
         mode = os.environ.get("REPRO_QUANT", "auto").strip().lower()
         if mode in ("never", "0", "off"):
@@ -171,13 +173,8 @@ class InferenceEngine:
             return "f32"
         if mode not in ("force", "1") and jax.default_backend() != "tpu":
             return "f32"
-        try:
-            from repro.quant.gate import gate_passed
-            if not gate_passed(self.path):
-                return "f32"
-        except Exception:
-            return "f32"
-        return "int8"
+        from repro.quant.gate import gate_passed
+        return "int8" if gate_passed(self.path) else "f32"
 
     def _quantize_residency(self):
         """Quantize the dense stack once at load (per-output-channel

@@ -12,9 +12,10 @@ steps, implemented here as runtime functions over JAX arrays:
      tensor (app -> tensor direction only).
 
 Direction ``to`` maps application memory -> tensor space (gather);
-``from`` maps tensor space -> application memory (window writes).  The
-stencil fast path is served by ``repro.kernels.stencil_gather`` on TPU;
-this jnp implementation is the portable path and the kernel's oracle.
+``from`` maps tensor space -> application memory (window writes).  This
+jnp implementation is the path every region runs, on every backend.
+``repro.kernels.stencil_gather`` is a Pallas kernel for the same stencil
+gather (bit-exact against its jnp oracle), but no region calls it yet.
 """
 from __future__ import annotations
 

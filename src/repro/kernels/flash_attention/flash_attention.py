@@ -56,7 +56,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, block_k, causal, q_offset,
 
 
 def flash_attention(q, k, v, *, causal=True, block_q=128, block_k=128,
-                    q_offset=0, kv_valid_len=None, interpret=True):
+                    q_offset=0, kv_valid_len=None, interpret):
     """q: [B, Sq, H, hd]; k, v: [B, Skv, KV, hd] with H % KV == 0."""
     B, Sq, H, hd = q.shape
     Skv, KV = k.shape[1], k.shape[2]

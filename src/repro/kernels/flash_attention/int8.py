@@ -90,7 +90,7 @@ def _kernel(q_ref, kq_ref, ks_ref, vq_ref, vs_ref, o_ref, *, block_k,
 
 def flash_attention_int8(q, kq, ks, vq, vs, *, causal=True, block_q=128,
                          block_k=128, q_offset=0, kv_valid_len=None,
-                         interpret=True):
+                         interpret):
     """q: [B, Sq, H, hd] float; kq/vq: int8 [B, Skv, KV, hd];
     ks: f32 [B, Skv, KV, 1] per-token; vs: f32 [B, 1, KV, hd]
     per-channel (see :func:`repro.quant.quantize.quantize_kv`)."""

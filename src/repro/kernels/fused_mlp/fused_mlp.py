@@ -38,7 +38,11 @@ def _kernel(*refs, n_layers, acts):
     for l in range(n_layers):
         w = wb[2 * l][...]
         b = wb[2 * l + 1][...]
-        h = jnp.dot(h, w, preferred_element_type=jnp.float32) + b
+        # Mosaic's default contracts f32 operands in one bf16 pass (on a
+        # v5e, 5.8e-3 of max|y| off an f32 reference at 5-512-512-1);
+        # the f32 tier must serve f32 numbers
+        h = jnp.dot(h, w, preferred_element_type=jnp.float32,
+                    precision=jax.lax.Precision.HIGHEST) + b
         h = _ACTS[acts[l]](h)
     o_ref[...] = h.astype(o_ref.dtype)
 
@@ -68,7 +72,7 @@ def fits_vmem(widths, batch_tile=128, budget=None, dtype_bytes=4):
 
 
 def fused_mlp(x, weights, biases, acts, *, batch_tile: int = 128,
-              interpret: bool = True):
+              interpret: bool):
     """x: [B, F0]; weights: list of [F_l, F_{l+1}]; acts: per-layer name."""
     B, F0 = x.shape
     n_layers = len(weights)

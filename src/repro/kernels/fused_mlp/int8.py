@@ -87,7 +87,7 @@ def fits_vmem_int8(widths, batch_tile=128, budget=None, act_bytes=4):
 
 
 def fused_mlp_int8(x, qlayers, acts, *, batch_tile: int = 128,
-                   interpret: bool = True):
+                   interpret: bool):
     """x: [B, F0] float; qlayers: [(wq int8 [Fi,Fo], ws f32 [Fo],
     b f32 [Fo]), ...]; acts: per-layer activation name."""
     B, F0 = x.shape
@@ -227,7 +227,6 @@ def fused_mlp_int8_sharded(x, qlayers, acts, *, mesh, data_axes,
         return fused_mlp_int8_op(x, qlayers, acts,
                                  force_kernel=force_kernel,
                                  batch_tile=batch_tile)
-    from jax.experimental.shard_map import shard_map
     ax = data_axes[0] if len(data_axes) == 1 else tuple(data_axes)
     xspec = P(*((ax,) + (None,) * (x.ndim - 1)))
 
@@ -235,8 +234,8 @@ def fused_mlp_int8_sharded(x, qlayers, acts, *, mesh, data_axes,
         return fused_mlp_int8_op(xs, qs, acts, force_kernel=force_kernel,
                                  batch_tile=batch_tile)
 
-    f = shard_map(local, mesh=mesh, in_specs=(xspec, P()),
-                  out_specs=xspec, check_rep=False)
+    f = jax.shard_map(local, mesh=mesh, in_specs=(xspec, P()),
+                      out_specs=xspec, check_vma=False)
     return f(x, [tuple(q) for q in qlayers])
 
 

@@ -147,7 +147,6 @@ def fused_mlp_sharded(x, weights, biases, acts, *, mesh, data_axes,
         return fused_mlp_op(x, weights, biases, acts,
                             force_kernel=force_kernel,
                             batch_tile=batch_tile)
-    from jax.experimental.shard_map import shard_map
     ax = data_axes[0] if len(data_axes) == 1 else tuple(data_axes)
     xspec = P(*((ax,) + (None,) * (x.ndim - 1)))
 
@@ -157,8 +156,8 @@ def fused_mlp_sharded(x, weights, biases, acts, *, mesh, data_axes,
         return fused_mlp_op(xs, ws, bs, acts, force_kernel=force_kernel,
                             batch_tile=batch_tile)
 
-    f = shard_map(local, mesh=mesh, in_specs=(xspec, P(), P()),
-                  out_specs=xspec, check_rep=False)
+    f = jax.shard_map(local, mesh=mesh, in_specs=(xspec, P(), P()),
+                      out_specs=xspec, check_vma=False)
     return f(x, list(weights), list(biases))
 
 

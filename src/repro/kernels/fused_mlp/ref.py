@@ -17,5 +17,8 @@ _ACTS = {
 def fused_mlp_ref(x, weights, biases, acts):
     h = x.astype(jnp.float32)
     for w, b, a in zip(weights, biases, acts):
-        h = _ACTS[a](h @ w + b)
+        # the kernel contracts at HIGHEST; so must its oracle, or on a TPU
+        # it would run one bf16 pass and stop being the f32 ground truth
+        h = _ACTS[a](jnp.dot(h, w, precision=jax.lax.Precision.HIGHEST)
+                     + b)
     return h.astype(x.dtype)

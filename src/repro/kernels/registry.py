@@ -45,24 +45,28 @@ _DISPATCHES = _m.counter(
     ("kernel", "provenance", "tier"))
 
 # ------------------------------------------------------------ VMEM budget ---
-# Every shipping TPU generation (v2 through v6e) exposes ~16 MiB of VMEM
-# per TensorCore (see the TPU memory-hierarchy docs), so the kind-keyed
-# budget is a single constant today.  The *budget* leaves a reserve for
-# the compiler's own scratch (semaphores, spills, double-buffering
-# bookkeeping) — the same 4 MiB headroom the old hardcoded 12 MiB budget
-# implied on a 16 MiB part.  The ``device_kind`` parameter stays in the
-# signature (and in the lru key) so per-generation entries have an
-# obvious landing spot the moment a part diverges.
-_VMEM_PHYSICAL = 16 * 2 ** 20
-_VMEM_RESERVE = 4 * 2 ** 20
+# Budget the kernels' cost models (``fits``) may spend, by ``device_kind``.
+# The models count each weight once and each activation tile twice; the
+# compiler double-buffers every input.  For "TPU v5 lite" (v5e) the
+# compiler accepts the f32 fused MLP up to 5-1920-1920-1 at tile 128 and
+# refuses 5-2048-2048-1 at tile 8, which fits a 32 MiB kernel limit; 12
+# MiB of modelled bytes stays inside it with room for Mosaic's own
+# scratch.  ``tests/test_tpu_compile.py`` compiles the largest net each
+# model admits at this budget.  A kind missing here is an error: a guess
+# would only show up as a compile refusal on the chip.
+_VMEM_BUDGET_BY_KIND = {"TPU v5 lite": 12 * 2 ** 20}
 _OFF_TPU_BUDGET = 12 * 2 ** 20  # interpret mode: keep the old constant
 
 
 def _vmem_budget_for_kind(device_kind: str) -> int:
-    """Usable VMEM budget for a TPU ``device_kind`` string ("TPU v4",
-    "TPU v5 lite", ...): physical size minus the compiler reserve."""
-    del device_kind  # uniform across shipping generations — see above
-    return _VMEM_PHYSICAL - _VMEM_RESERVE
+    """Usable VMEM budget for a TPU ``device_kind`` string ("TPU v5
+    lite", ...); raises ``ValueError`` for a kind with no entry."""
+    try:
+        return _VMEM_BUDGET_BY_KIND[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no VMEM budget for TPU device kind {device_kind!r}; known: "
+            f"{sorted(_VMEM_BUDGET_BY_KIND)}") from None
 
 
 @functools.lru_cache(maxsize=None)
@@ -75,17 +79,13 @@ def _device_vmem_budget_cached(backend: str, device_kind: str) -> int:
 def device_vmem_budget() -> int:
     """VMEM byte budget of the backend this process dispatches to.
 
-    Queried from the device (kind-keyed: VMEM size is a property of the
-    TPU generation, not exposed by ``memory_stats()``, which reports
-    HBM); off-TPU — where kernels only ever run in interpret mode —
-    the old 12 MiB constant is kept so tuner decisions stay
-    deterministic in CI.
+    Keyed by the device's kind (VMEM size is a property of the TPU
+    generation, not exposed by ``memory_stats()``, which reports HBM);
+    off-TPU — where kernels only ever run in interpret mode — the old
+    12 MiB constant is kept so tuner decisions stay deterministic in CI.
     """
     backend = jax.default_backend()
-    try:
-        kind = jax.devices()[0].device_kind if backend == "tpu" else ""
-    except Exception:
-        kind = ""
+    kind = jax.devices()[0].device_kind if backend == "tpu" else ""
     return _device_vmem_budget_cached(backend, kind)
 
 
@@ -198,18 +198,14 @@ def tuned_params(spec: KernelSpec, problem: dict) -> Dict[str, int]:
     """Validated tune-cache winner for ``problem``, or {} when untuned.
 
     Runs at trace time (the op shims call it while the engine's apply is
-    being traced), so a cache problem must degrade to the defaults, not
-    raise into the trace.
+    being traced).  The cache reads a missing or corrupt file as a miss,
+    so the defaults apply; any other error is a fault and propagates.
     """
     if not spec.params:
         return {}
-    try:
-        from repro.tune.cache import best_params
-        return best_params(spec.name,
-                           spec.lookup_keys(problem,
-                                            jax.default_backend())) or {}
-    except Exception:
-        return {}
+    from repro.tune.cache import best_params
+    return best_params(spec.name,
+                       spec.lookup_keys(problem, jax.default_backend())) or {}
 
 
 def resolve_params_info(spec: KernelSpec, problem: dict,

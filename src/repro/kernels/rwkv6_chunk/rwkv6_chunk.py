@@ -38,7 +38,7 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, o_ref, sT_ref, *,
     sT_ref[0, 0] = S.astype(sT_ref.dtype)
 
 
-def rwkv6_chunk(r, k, v, w, u, s0, *, interpret=True):
+def rwkv6_chunk(r, k, v, w, u, s0, *, interpret):
     """r,k,v,w: [B, T, H, hd]; u: [H, hd]; s0: [B, H, hd, hd].
 
     Returns (o [B, T, H, hd], sT [B, H, hd, hd]).

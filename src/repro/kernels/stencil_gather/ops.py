@@ -14,7 +14,7 @@ import numpy as np
 
 from repro.kernels import registry
 from repro.kernels.stencil_gather.ref import stencil_gather_ref
-from repro.kernels.stencil_gather.stencil_gather import stencil_gather
+from repro.kernels.stencil_gather.stencil_gather import halo, stencil_gather
 
 _H_LADDER = (8, 16, 32, 64)
 _W_LADDER = (128, 256, 512)
@@ -68,19 +68,18 @@ def _key(problem, backend):
 
 
 def _fits(problem, params, budget=None):
-    """The full (padded) source grid is VMEM-resident plus the gathered
-    output tile — whose last-dim F pads to a full lane group."""
+    """The full source grid, padded to whole blocks plus the aligned
+    halo, is VMEM-resident beside the ``[F, block_h, block_w]`` output
+    tile; the pipeline double-buffers both."""
     if budget is None:
         budget = registry.device_vmem_budget()
     bh, bw = params["block_h"], params["block_w"]
-    dy, dx = _halo(problem)
-    gh = problem["out_h"] + (-problem["out_h"] % bh) + max(0, dy)
-    gw = problem["out_w"] + (-problem["out_w"] % bw) + max(0, dx)
+    hh, hw = halo([_halo(problem)])
+    gh = max(problem["h"], registry.round_up(problem["out_h"], bh) + hh)
+    gw = max(problem["w"], registry.round_up(problem["out_w"], bw) + hw)
     t = registry.tile_bytes
-    grid_bytes = t(gh, gw)
-    out_tile = bh * registry.round_up(bw, 8) * \
-        registry.round_up(len(problem["offsets"]), 128) * 4
-    return grid_bytes + 2 * out_tile <= budget
+    out_tile = len(problem["offsets"]) * t(bh, bw)
+    return 2 * (t(gh, gw) + out_tile) <= budget
 
 
 def _cands(problem):

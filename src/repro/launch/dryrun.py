@@ -48,8 +48,6 @@ def _compile(cfg, shape, mesh, multi_pod):
 
 def _cost(compiled):
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # pre-0.5 jax: one dict per program
-        ca = ca[0] if ca else {}
     return float(ca.get("flops", 0.0)), float(ca.get("bytes accessed", 0.0))
 
 

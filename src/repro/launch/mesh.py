@@ -9,15 +9,11 @@ links.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-try:  # jax >= 0.5 explicit-sharding API; absent on the pinned 0.4.x
-    from jax.sharding import AxisType
 
-    def _axis_kwargs(n_axes):
-        return {"axis_types": (AxisType.Auto,) * n_axes}
-except ImportError:  # pre-AxisType jax: all mesh axes are implicitly auto
-    def _axis_kwargs(n_axes):
-        return {}
+def _axis_kwargs(n_axes):
+    return {"axis_types": (AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -422,7 +422,8 @@ def spawn_local_pod(n: int, target: str, args: tuple = (), *,
             ENV_NUM_PROCESSES: str(n),
             ENV_PROCESS_ID: str(pid),
             ENV_LOCAL_DEVICES: str(devices_per_host),
-            "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu"),
+            # a CPU drill: a child never takes the parent's accelerator
+            "JAX_PLATFORMS": "cpu",
             # children build their own device view; never inherit the
             # parent's partitioning (dryrun forces 512 devices at import)
             "XLA_FLAGS": f"{_HOST_DEVICE_FLAG}={devices_per_host}",

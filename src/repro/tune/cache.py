@@ -192,7 +192,7 @@ def _record_params(rec: Optional[dict]) -> Optional[Dict[str, int]]:
     config that failed the oracle check.  Schema-1 records that reached
     memory without migration still resolve via ``batch_tile``.
     """
-    if rec is None or not rec.get("exact", False):
+    if not isinstance(rec, dict) or not rec.get("exact", False):
         return None
     params = rec.get("params")
     if params is None and "batch_tile" in rec:

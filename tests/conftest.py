@@ -10,19 +10,10 @@ ROOT = pathlib.Path(__file__).resolve().parents[1]
 SRC = str(ROOT / "src")
 if SRC not in sys.path:
     sys.path.insert(0, SRC)
-if str(ROOT / "tests") not in sys.path:
-    sys.path.insert(0, str(ROOT / "tests"))
+
 
 def pytest_configure(config):
     config.addinivalue_line(
         "markers",
         "slow: spawns real multi-process jax pods (the multihost CI lane "
         "runs these; deselect with -m 'not slow' for quick iteration)")
-
-
-try:  # offline image has no hypothesis wheel; shim keeps the suite runnable
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:
-    import _hypothesis_shim
-    sys.modules["hypothesis"] = _hypothesis_shim
-    sys.modules["hypothesis.strategies"] = _hypothesis_shim.strategies

@@ -27,7 +27,8 @@ def test_stencil_gather_sweep(h, w, seed, dtype):
     x = jnp.asarray(rng.normal(size=(h, w)).astype(np.float32)).astype(dtype)
     offs = ((0, 1), (2, 0), (1, 1), (0, 0), (1, 2))
     oh, ow = h - 3, w - 3
-    a = stencil_gather(x, offs, oh, ow, origin=(1, 1), block_h=8, block_w=16)
+    a = stencil_gather(x, offs, oh, ow, origin=(1, 1), block_h=8, block_w=16,
+                       interpret=True)
     b = stencil_gather_ref(x, offs, oh, ow, origin=(1, 1))
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
@@ -45,7 +46,7 @@ def test_fused_mlp_sweep(widths, acts, batch):
     bs = [jnp.asarray(rng.normal(size=(b,)).astype(np.float32) * 0.1)
           for b in widths[1:]]
     x = jnp.asarray(rng.normal(size=(batch, widths[0])).astype(np.float32))
-    a = fused_mlp(x, ws, bs, acts, batch_tile=32)
+    a = fused_mlp(x, ws, bs, acts, batch_tile=32, interpret=True)
     b = fused_mlp_ref(x, ws, bs, acts)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5,
                                atol=2e-5)
@@ -66,7 +67,8 @@ def test_flash_attention_sweep(b, sq, kv_heads, group, causal, dtype):
     q = jnp.asarray(rng.normal(size=(b, sq, H, 16)).astype(np.float32)).astype(dtype)
     k = jnp.asarray(rng.normal(size=(b, sq, kv_heads, 16)).astype(np.float32)).astype(dtype)
     v = jnp.asarray(rng.normal(size=(b, sq, kv_heads, 16)).astype(np.float32)).astype(dtype)
-    a = flash_attention(q, k, v, causal=causal, block_q=32, block_k=32)
+    a = flash_attention(q, k, v, causal=causal, block_q=32, block_k=32,
+                        interpret=True)
     r = flash_attention_ref(q, k, v, causal=causal)
     tol = 5e-2 if dtype == "bfloat16" else 1e-5
     np.testing.assert_allclose(np.asarray(a, np.float32),
@@ -79,7 +81,7 @@ def test_flash_attention_kv_valid_len():
     k = jnp.asarray(rng.normal(size=(1, 64, 2, 16)).astype(np.float32))
     v = jnp.asarray(rng.normal(size=(1, 64, 2, 16)).astype(np.float32))
     a = flash_attention(q, k, v, causal=False, kv_valid_len=40, block_q=8,
-                        block_k=16)
+                        block_k=16, interpret=True)
     r = flash_attention_ref(q[:, :, :, :], k[:, :40], v[:, :40], causal=False)
     np.testing.assert_allclose(np.asarray(a), np.asarray(r), rtol=1e-5,
                                atol=1e-5)
@@ -95,7 +97,7 @@ def test_rwkv6_chunk_sweep(T, hd):
     w = jnp.asarray(rng.uniform(0.7, 0.999, (B, T, H, hd)).astype(np.float32))
     u = jnp.asarray(rng.normal(size=(H, hd)).astype(np.float32))
     s0 = jnp.asarray(rng.normal(size=(B, H, hd, hd)).astype(np.float32)) * 0.1
-    oa, sa = rwkv6_chunk(r, k, v, w, u, s0)
+    oa, sa = rwkv6_chunk(r, k, v, w, u, s0, interpret=True)
     ob, sb = rwkv6_chunk_ref(r, k, v, w, u, s0)
     np.testing.assert_allclose(np.asarray(oa), np.asarray(ob), rtol=1e-5,
                                atol=1e-5)

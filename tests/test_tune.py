@@ -149,6 +149,20 @@ def test_best_params_namespaced_per_kernel(tmp_path, monkeypatch):
         {"block_q": 32, "block_kv": 64}  # ordered fallback chain
 
 
+def test_corrupt_record_is_a_miss(tmp_path, monkeypatch):
+    # a well-formed file whose record is not a dict: the cache's own
+    # corruption, read as a miss (the registry no longer catches errors)
+    import json
+    from repro.tune import best_params
+    import repro.tune.cache as cache_mod
+    p = tmp_path / "fused_mlp.json"
+    p.write_text(json.dumps({"schema": cache_mod.SCHEMA,
+                             "kernel": "fused_mlp", "entries": {"k": 5}}))
+    monkeypatch.setattr(cache_mod, "_default",
+                        {"fused_mlp": TuneCache("fused_mlp", p)})
+    assert best_params("fused_mlp", ["k"]) is None
+
+
 def test_shape_key_stable():
     assert shape_key([5, 16, 1], jnp.float32, "cpu", 256) == \
         shape_key((5, 16, 1), jnp.float32, "cpu", 256)
