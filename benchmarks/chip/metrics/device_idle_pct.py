@@ -1,0 +1,10 @@
+"""Device: the share of the traced window in which no operation ran on
+the device, in percent, the mean over the cell's chips (profiler
+trace)."""
+
+
+def read(rec):
+    tr = rec["trace"]
+    if tr is None or tr["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])
