@@ -231,7 +231,9 @@ def export_trace(path) -> None:
     enqueue->resolve window: queue.submit + serve.request tile it by
     construction, so any request whose union coverage drops below
     :data:`TRACE_MIN_COVERAGE` means an instrumentation gap crept into
-    the serve path.
+    the serve path.  Coverage is taken over those two spans alone: the
+    region spans sharing the id also hold the caller's time around the
+    queue (its other calls, its flush) before ``result()``.
     """
     from repro.obs import TRACER, default_registry, request_coverage
     path = pathlib.Path(path)
@@ -242,7 +244,9 @@ def export_trace(path) -> None:
             ( (e.get("args") or {}).get("trace") for e in events
               if e["name"] == "queue.submit" )
             if t is not None}
-    cov = {t: c for t, c in request_coverage(events).items()
+    served = [e for e in events
+              if e["name"] in ("queue.submit", "serve.request")]
+    cov = {t: c for t, c in request_coverage(served).items()
            if t in full and c["spans"] >= 2}
     if not cov:
         raise SystemExit("--trace: no fully-sampled request in the trace "

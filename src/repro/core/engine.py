@@ -46,7 +46,9 @@ def _mute_donation_warning_off_tpu():
 
 from repro.dist.sharding import constrain, current_ctx
 from repro.nn.serialize import load_model
-from repro.obs import TRACER
+from repro.obs import TRACER, watch_compiles
+
+watch_compiles()
 
 
 def bundle_norm(spec, net):
@@ -100,10 +102,6 @@ class InferenceEngine:
         # resolved NamedSharding per (shape, mesh, multi_pod): spec_for is
         # pure python over every dim and was re-run on every eager call
         self._shardings: dict = {}
-        # (apply id, batch shape) pairs already executed once: a batched
-        # apply whose pair is unseen is paying its jit compile, and the
-        # obs span marks it so — compile spikes stop looking like serving
-        self._seen_shapes: set = set()
         self._load()
 
     def _load(self):
@@ -115,7 +113,6 @@ class InferenceEngine:
         self._mtime = _bundle_mtime(self.path)
         self._applies.clear()
         self._shardings.clear()
-        self._seen_shapes.clear()
         # precision tier is a load-time property: the gate verdict is
         # bound to the bundle fingerprint, so any reload re-resolves it
         # (gate_bundle() invalidates the engine cache after a verdict)
@@ -199,14 +196,21 @@ class InferenceEngine:
                    ("bundle",)).inc(1, bundle=self.path)
 
     @classmethod
-    def get(cls, model_path) -> "InferenceEngine":
+    def get(cls, model_path, trace=None) -> "InferenceEngine":
         """Process-wide cache: a model file is loaded once (paper §IV-B).
 
         A bundle rewritten on disk since it was loaded (NAS retraining)
         is transparently reloaded in place, so long-lived regions holding
-        this engine see the fresh weights.
+        this engine see the fresh weights.  ``trace`` is the trace id of
+        a traced region call: the lookup is then its ``engine.get`` span.
         """
-        key = str(model_path)
+        if trace is None:
+            return cls._get(str(model_path))
+        with TRACER.child("engine.get", trace, cat="engine"):
+            return cls._get(str(model_path))
+
+    @classmethod
+    def _get(cls, key: str) -> "InferenceEngine":
         with cls._cache_lock:
             eng = cls._cache.get(key)
             if eng is None:
@@ -275,6 +279,8 @@ class InferenceEngine:
             def raw(params, x):
                 return net.apply(params, x)
 
+        # the name makes the compiled module ``jit_apply_fn``, which the
+        # chip benchmark's apply_roofline finds in the device trace
         def apply_fn(params, x):
             x = constrain(x, *(("data",) + (None,) * (x.ndim - 1)))
             if norm is not None:
@@ -393,18 +399,7 @@ class InferenceEngine:
             _m.counter("repro_quant_served_rows_total",
                        "rows served by the gated int8 tier",
                        ("bundle",)).inc(n, bundle=self.path)
-        if TRACER.enabled and not isinstance(x, jax.core.Tracer):
-            shape_key = (id(fn), tuple(x.shape))
-            first = shape_key not in self._seen_shapes
-            with TRACER.span("engine.apply", cat="engine",
-                             args={"path": self.path, "rows": n,
-                                   "bucket": int(x.shape[0]),
-                                   "tier": self.tier,
-                                   "donate": donate, "compile": first}):
-                y = fn(self.params, x)
-            self._seen_shapes.add(shape_key)
-        else:
-            y = fn(self.params, x)
+        y = fn(self.params, x)
         if fault is not None and fault.mode in ("nan", "inf"):
             # eager elementwise op: poisons every row while preserving
             # the output's sharding (works on global pod arrays too)

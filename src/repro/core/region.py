@@ -75,22 +75,33 @@ class AsyncRegionResult:
 
     def result(self, timeout: Optional[float] = None) -> dict:
         if self._done is None:
-            try:
-                Y = self._future.result(timeout)
-            except TimeoutError:
-                raise  # not a surrogate failure: the caller set the budget
-            except Exception:
-                # zero-lost contract: a failed dispatch (injected fault,
-                # non-finite screen, dead dispatcher) degrades to the
-                # accurate path instead of surfacing the serve error
-                region = self._region
-                if not (BREAKERS.enabled and region.model_path):
-                    raise
-                BREAKERS.record_failure(region.model_path)
-                self._done = region._fallback(self._arrays, "result")
-                return self._done
-            self._done = self._region._bridge_from_jit(Y, self._arrays)
+            trace = self._future.trace  # the region call's, when traced
+            if trace is None:
+                self._done = self._resolve(timeout, None)
+            else:
+                with TRACER.span("region.result", cat="region",
+                                 trace=trace):
+                    self._done = self._resolve(timeout, trace)
         return self._done
+
+    def _resolve(self, timeout: Optional[float], trace: Optional[str]
+                 ) -> dict:
+        region = self._region
+        try:
+            with TRACER.child("region.wait", trace, cat="region"):
+                Y = self._future.result(timeout)
+        except TimeoutError:
+            raise  # not a surrogate failure: the caller set the budget
+        except Exception:
+            # zero-lost contract: a failed dispatch (injected fault,
+            # non-finite screen, dead dispatcher) degrades to the
+            # accurate path instead of surfacing the serve error
+            if not (BREAKERS.enabled and region.model_path):
+                raise
+            BREAKERS.record_failure(region.model_path)
+            return region._fallback(self._arrays, "result")
+        with TRACER.child("region.bridge_out", trace, cat="region"):
+            return region._bridge_from_jit(Y, self._arrays)
 
 
 class MLRegion:
@@ -171,19 +182,35 @@ class MLRegion:
         return out
 
     # ------------------------------------------------------- execution ----
-    def engine(self) -> InferenceEngine:
+    def engine(self, trace: Optional[str] = None) -> InferenceEngine:
         assert self.model_path, f"region {self.name}: no model path"
         # always resolve through the process-wide cache: get() is a dict
         # lookup + bundle-mtime stat, and it is what reloads a bundle the
         # NAS loop retrained under this region's feet
-        return InferenceEngine.get(self.model_path)
+        return InferenceEngine.get(self.model_path, trace)
 
-    def _rows_in(self, arrays: dict):
-        """Bridge app arrays to engine-shaped f32 rows [n, *in_shape[1:]]."""
-        X = self._bridge_in_jit(arrays)
-        eng = self.engine()
+    def _rows_in(self, arrays: dict, trace: Optional[str] = None,
+                 args: Optional[dict] = None):
+        """Bridge app arrays to engine-shaped f32 rows [n, *in_shape[1:]].
+        A traced call passes its trace id and its span's ``args``."""
+        eng = self.engine(trace)
         in_shape = tuple(eng.spec["in_shape"])
-        return eng, X.reshape((-1,) + in_shape[1:]).astype(jnp.float32)
+        with TRACER.child("region.bridge_in", trace, cat="region"):
+            X = self._bridge_in_jit(arrays)
+            X = X.reshape((-1,) + in_shape[1:]).astype(jnp.float32)
+        if args is not None:
+            args["rows"] = int(X.shape[0])
+        return eng, X
+
+    def _call_span(self, body, arrays: dict):
+        """``body(arrays, trace, args)`` inside this eager call's
+        ``region.call`` span, under a trace id minted here; only called
+        with tracing on."""
+        trace = TRACER.new_trace_id()
+        args = {"region": self.name}
+        with TRACER.span("region.call", cat="region", trace=trace,
+                         args=args):
+            return body(arrays, trace, args)
 
     def _fallback(self, arrays: dict, path: str) -> dict:
         """Serve this invocation from the accurate path (breaker OPEN or
@@ -196,12 +223,18 @@ class MLRegion:
 
     def _infer(self, arrays: dict):
         traced = _is_traced(arrays)
+        if traced or not TRACER.enabled:
+            return self._infer_call(arrays, None, None, traced)
+        return self._call_span(self._infer_call, arrays)
+
+    def _infer_call(self, arrays: dict, trace: Optional[str],
+                    args: Optional[dict], traced: bool = False):
         use_breaker = (BREAKERS.enabled and self.model_path is not None
                        and not traced)
         if use_breaker and not BREAKERS.allow(self.model_path):
             return self._fallback(arrays, "infer")
         try:
-            eng, Xb = self._rows_in(arrays)
+            eng, Xb = self._rows_in(arrays, trace, args)
             Y = eng(Xb)
         except Exception:
             if not use_breaker:
@@ -212,7 +245,8 @@ class MLRegion:
             BREAKERS.record_success(self.model_path)
         if SHADOW.enabled and not _is_traced(arrays, Xb) and SHADOW.sample():
             self._shadow_submit(arrays, rows=int(Xb.shape[0]), Y=Y)
-        return self._bridge_from_jit(Y, arrays)
+        with TRACER.child("region.bridge_out", trace, cat="region"):
+            return self._bridge_from_jit(Y, arrays)
 
     def _infer_async(self, arrays: dict) -> AsyncRegionResult:
         """Enqueue this invocation on the serve queue, keyed (multiplexed)
@@ -221,6 +255,12 @@ class MLRegion:
         if _is_traced(arrays):
             return AsyncRegionResult(self, arrays,
                                      resolved=self._infer(arrays))
+        if not TRACER.enabled:
+            return self._enqueue(arrays, None, None)
+        return self._call_span(self._enqueue, arrays)
+
+    def _enqueue(self, arrays: dict, trace: Optional[str],
+                 args: Optional[dict]) -> AsyncRegionResult:
         if (BREAKERS.enabled and self.model_path is not None
                 and not BREAKERS.allow(self.model_path)):
             # breaker OPEN (or HALF_OPEN non-probe): resolve through the
@@ -228,9 +268,9 @@ class MLRegion:
             return AsyncRegionResult(
                 self, arrays,
                 resolved=self._fallback(arrays, "infer_async"))
-        eng, Xb = self._rows_in(arrays)
+        eng, Xb = self._rows_in(arrays, trace, args)
         del eng  # resolved for bundle load/reload; batcher re-gets per batch
-        fut = self.serving.submit(self.model_path, Xb)
+        fut = self.serving.submit(self.model_path, Xb, trace=trace)
         if SHADOW.enabled and SHADOW.sample():
             self._shadow_submit(arrays, rows=int(Xb.shape[0]), future=fut)
         return AsyncRegionResult(self, arrays, future=fut)

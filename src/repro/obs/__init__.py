@@ -8,7 +8,7 @@ it is safe at any layer including ``launch.multihost`` pre-bootstrap.
 """
 from .trace import (TRACER, Span, Tracer, disable_tracing, enable_tracing,
                     export_chrome_trace, get_tracer, merge_chrome_traces,
-                    request_coverage, tracing_enabled)
+                    request_coverage, tracing_enabled, watch_compiles)
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       default_registry, note_static_fallback, warn_once)
 from .quality import (CRITICAL, LEVELS, OK, SHADOW, WARN, AlertMachine,
@@ -21,7 +21,7 @@ from .pod import (local_snapshot, merge_pod_trace, pod_quality_report,
 __all__ = [
     "TRACER", "Span", "Tracer", "enable_tracing", "disable_tracing",
     "tracing_enabled", "get_tracer", "export_chrome_trace",
-    "merge_chrome_traces", "request_coverage",
+    "merge_chrome_traces", "request_coverage", "watch_compiles",
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "default_registry",
     "warn_once", "note_static_fallback",
     "SHADOW", "ShadowScorer", "AlertMachine", "get_shadow",

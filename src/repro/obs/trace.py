@@ -10,12 +10,14 @@ attribute check returning a shared no-op context, so instrumented code
 pays nothing measurable when tracing is off (the serve-bench overhead
 gate holds this at <2% even *enabled*).
 
-Trace ids are minted by :meth:`Tracer.new_trace_id` at
-``ServeQueue.submit`` and ride the request object through coalescing,
-dispatch, and scatter — spans recorded from the submitter thread, the
+Trace ids are minted by :meth:`Tracer.new_trace_id` at an eager region
+call (``region.call``), or at ``ServeQueue.submit`` when none is passed
+in, and ride the request object through coalescing, dispatch, scatter and
+the caller's ``result()`` — spans recorded from the caller's thread, the
 dispatcher thread, and a pod-collective dispatch all carry the same id,
 which is what makes a request's end-to-end latency decomposable after
-the fact (queued → gathered → applied → landed → scattered).
+the fact (bridged → queued → gathered → applied → landed → scattered →
+bridged back).
 
 Export is Chrome ``trace_event`` JSON (:meth:`export_chrome_trace`) —
 open it at ``chrome://tracing`` or https://ui.perfetto.dev.  Timestamps
@@ -25,8 +27,10 @@ traces from different processes on one machine merge on a shared
 timeline (``repro.obs.pod``).
 
 ``annotate=True`` additionally enters a ``jax.profiler.TraceAnnotation``
-for every span, so spans line up with XLA's own timeline when a TPU
-profile is being captured alongside.
+for every :meth:`Tracer.span` and :meth:`Tracer.child`, so spans line up
+with XLA's own timeline when a TPU profile is being captured alongside.
+Spans written after the fact (:meth:`Tracer.record` / :meth:`Tracer.rec`:
+``serve.request``, ``xla.compile``) reach the program tracer only.
 """
 from __future__ import annotations
 
@@ -207,6 +211,14 @@ class Tracer:
             return _NULL
         return _LiveSpan(self, name, cat, trace, args)
 
+    def child(self, name: str, trace: Optional[str], *, cat: str = "serve"):
+        """``span()`` for one step of a traced call: a no-op when
+        ``trace`` is None, i.e. the call was not traced (tracing was off
+        when it began, or it runs inside a jit trace)."""
+        if trace is None or not self.enabled:
+            return _NULL
+        return _LiveSpan(self, name, cat, trace, None)
+
     # ----------------------------------------------------------- export ---
     def drop_counts(self) -> Dict[str, int]:
         """Per-thread-name totals of ring entries evicted on wrap.
@@ -292,6 +304,40 @@ if os.environ.get(ENV_TRACE, "") not in ("", "0"):
                   not in ("", "0"))
 
 
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compiles_watched = False
+_compiles_lock = threading.Lock()
+
+
+def _on_compile(event: str, duration: float, **kwargs) -> None:
+    if event != _COMPILE_EVENT:
+        return
+    t1 = time.monotonic()
+    from . import metrics as _metrics
+    _metrics.counter("repro_xla_compile_total",
+                     "XLA backend compiles in this process").inc(1)
+    if TRACER.enabled:
+        fun = kwargs.get("fun_name")
+        TRACER.rec("xla.compile", "xla", t1 - duration, t1, None,
+                   {"fun": fun} if fun is not None else None)
+
+
+def watch_compiles() -> None:
+    """Count every XLA backend compile of this process, on whatever
+    thread it happens, in ``repro_xla_compile_total``; with tracing on,
+    also record it as an ``xla.compile`` span.  Registers one
+    ``jax.monitoring`` listener; later calls do nothing.  JAX reports an
+    executable loaded from the persistent compilation cache as a backend
+    compile too, so such a load is counted."""
+    global _compiles_watched
+    with _compiles_lock:
+        if _compiles_watched:
+            return
+        import jax.monitoring
+        jax.monitoring.register_event_duration_secs_listener(_on_compile)
+        _compiles_watched = True
+
+
 def get_tracer() -> Tracer:
     return TRACER
 
@@ -338,13 +384,16 @@ def merge_chrome_traces(event_lists: List[List[dict]], path=None
 
 # ------------------------------------------------------- trace analysis ----
 def request_coverage(events: List[dict]) -> Dict[str, dict]:
-    """Per-trace-id span coverage of the measured enqueue→resolve window.
+    """Per-trace-id span coverage of a request's measured window.
 
     For every trace id, the window is [earliest span start, latest span
     end] and coverage is the union of its spans' intervals over that
-    window — 1.0 means no unaccounted gap anywhere between a request
-    entering ``submit`` and its future resolving.  The serve-bench
-    ``--trace`` gate requires >= 0.95 for every sampled request.
+    window — 1.0 means no unaccounted gap anywhere between the request's
+    first span (``region.call`` of a traced region call, else
+    ``queue.submit``) and its last (``region.bridge_out``, else
+    ``serve.request``).  Pass only ``queue.submit`` and ``serve.request``
+    events to audit the enqueue→resolve part alone: the serve-bench
+    ``--trace`` gate requires >= 0.95 there for every sampled request.
     """
     per: Dict[str, List[tuple]] = {}
     for ev in events:

@@ -402,24 +402,23 @@ class Batcher:
         # coverage audits close; queued time is recoverable inside it as
         # (batch.gather.ts - this span's ts).  One args dict serves every
         # request of the batch (rec() documents shared-args safety).
-        rargs = {"key": key, "batch": bid, "reason": reason} if traced \
-            else None
-        for i, r in enumerate(requests):
-            if i in bad:
-                r.future.set_exception(NonFiniteOutput(key, r.n))
-                bad_rows += r.n
-                off += r.n
-                continue
-            r.future.set_result(Y[off:off + r.n])
-            off += r.n
-            lats.append(t1 - r.t_enqueue)
-            if traced:
-                tr.rec("serve.request", "serve", r.t_enqueue,
-                       time.monotonic(), r.trace, rargs)
+        rargs = sargs = None
         if traced:
-            tr.record("batch.scatter", t1, time.monotonic(), cat="batch",
-                      args={"key": key, "batch": bid,
-                            "requests": len(requests)})
+            rargs = {"key": key, "batch": bid, "reason": reason}
+            sargs = {"key": key, "batch": bid, "requests": len(requests)}
+        with tr.span("batch.scatter", cat="batch", args=sargs):
+            for i, r in enumerate(requests):
+                if i in bad:
+                    r.future.set_exception(NonFiniteOutput(key, r.n))
+                    bad_rows += r.n
+                    off += r.n
+                    continue
+                r.future.set_result(Y[off:off + r.n])
+                off += r.n
+                lats.append(t1 - r.t_enqueue)
+                if traced:
+                    tr.rec("serve.request", "serve", r.t_enqueue,
+                           time.monotonic(), r.trace, rargs)
         self._note_served(requests, bad, lats)
         if bad:
             _NONFINITE.inc(bad_rows, key=key)

@@ -296,8 +296,8 @@ class ServeQueue:
         return self.tenancy.offenders()
 
     # ----------------------------------------------------------- submit ---
-    def submit(self, key: str, rows, *,
-               tenant: Optional[str] = None) -> ServeFuture:
+    def submit(self, key: str, rows, *, tenant: Optional[str] = None,
+               trace: Optional[str] = None) -> ServeFuture:
         """Queue ``rows`` ([n, ...features], n >= 1) for bundle ``key``.
 
         With a tenancy board attached, ``tenant`` names the submitting
@@ -306,7 +306,21 @@ class ServeQueue:
         (``policy.block``) or raises
         :class:`repro.serve.tenancy.TenantThrottled` — and the tenant's
         pending-row cap backpressures under the global one.
+
+        With tracing on, the request carries ``trace`` (a traced region
+        call passes its own id) or an id minted here, and the whole call
+        is its ``queue.submit`` span.
         """
+        if not TRACER.enabled:
+            return self._submit(key, rows, tenant, None, None)
+        trace = trace or TRACER.new_trace_id()
+        args = {"key": key}
+        with TRACER.span("queue.submit", cat="queue", trace=trace,
+                         args=args):
+            return self._submit(key, rows, tenant, trace, args)
+
+    def _submit(self, key: str, rows, tenant: Optional[str],
+                trace: Optional[str], args: Optional[dict]) -> ServeFuture:
         from repro.dist.sharding import current_ctx
         board = self.tenancy
         if board is not None:
@@ -316,6 +330,8 @@ class ServeQueue:
         if x.ndim < 1 or x.shape[0] < 1:
             raise ValueError(f"submit needs [n, ...] rows, got {x.shape}")
         n = int(x.shape[0])
+        if args is not None:
+            args["rows"] = n
         if board is not None:
             # token-bucket admission happens at the door, outside every
             # lock: refill is wall-clock, so a blocked submit sleeps in
@@ -324,7 +340,6 @@ class ServeQueue:
                         timeout_s=self.policy.block_timeout_s)
         fut = ServeFuture(self, key)
         t_sub = time.monotonic()
-        trace = TRACER.new_trace_id() if TRACER.enabled else None
         fut.trace = trace  # shadow scoring rides the same id
         req = _Request(key, x, n, fut, t_sub, current_ctx(), trace, tenant)
         deadline = t_sub + self.policy.block_timeout_s
@@ -372,14 +387,6 @@ class ServeQueue:
             if admitted:
                 if board is not None:
                     board.on_enqueue(tenant, key, n)
-                if trace is not None:
-                    # submitter-thread span: admission (incl. any time
-                    # blocked on backpressure).  The dispatcher's
-                    # serve.request span starts at t_enqueue, so together
-                    # the request's spans tile enqueue -> resolve gap-free.
-                    TRACER.rec("queue.submit", "queue", t_sub,
-                               time.monotonic(), trace,
-                               {"key": key, "rows": n})
                 if flush_inline:
                     self.flush(key, reason="max_batch")
                 return fut

@@ -231,8 +231,8 @@ def test_trace_id_rides_submit_to_dispatcher_thread(tmp_path):
     # recorded from different threads, same request id
     assert req.thread == "repro-serve-dispatch"
     assert sub.thread != req.thread
-    # the engine span rode the same dispatch
-    assert any(s.name == "engine.apply" for s in spans)
+    # the batch's apply span rode the same dispatch
+    assert any(s.name == "batch.apply" for s in spans)
     cov = request_coverage(TRACER.chrome_events())
     assert cov[sub.trace]["coverage"] >= 0.95
 
@@ -249,6 +249,153 @@ def test_inline_flush_spans_single_thread(tmp_path):
     sub = next(s for s in spans if s.name == "queue.submit")
     req = next(s for s in spans if s.name == "serve.request")
     assert sub.trace == req.trace and sub.thread == req.thread
+
+
+def _async_region(mp_path, queue, n=4):
+    from repro.core import approx_ml, tensor_functor
+
+    def accurate(x):
+        raise AssertionError("the surrogate serves every call here")
+
+    rngs = {"i": (0, n)}
+    return approx_ml(
+        accurate, name="obs-region",
+        inputs={"x": (tensor_functor("oin: [i, 0:2] = ([i, 0:2])"), rngs)},
+        outputs={"y": (tensor_functor("oout: [i, 0:1] = ([i, 0:1])"),
+                       rngs)},
+        mode="infer_async", model=mp_path, serving=queue)
+
+
+def _region_round_trip(reg, queue, mp_path, n=4):
+    handle = reg(x=_rows(n))
+    queue.flush(mp_path)
+    return handle.result(30)
+
+
+_REGION_TREE = {"region.call": ("engine.get", "region.bridge_in",
+                                "queue.submit"),
+                "region.result": ("region.wait", "region.bridge_out")}
+
+
+def test_region_call_span_tree_shares_one_trace_id(tmp_path):
+    """An eager infer_async call and its result(): region.call holds
+    engine.get, the input bridge and queue.submit; region.result holds
+    the wait and the output bridge; all of them, and the dispatcher's
+    serve.request, carry the one id the region call minted."""
+    mp_path = _bundle(tmp_path)
+    q = ServeQueue(FlushPolicy(max_batch_rows=1 << 30))
+    reg = _async_region(mp_path, q)
+    _region_round_trip(reg, q, mp_path)  # compiles outside the trace
+    enable_tracing()
+    TRACER.clear()
+    out = _region_round_trip(reg, q, mp_path)
+    assert out["y"].shape == (4, 1)
+    by_name = {}
+    for s in TRACER.events():
+        assert s.name not in by_name, f"{s.name} recorded twice"
+        by_name[s.name] = s
+    call = by_name["region.call"]
+    assert call.args == {"region": "obs-region", "rows": 4}
+    assert by_name["queue.submit"].args["rows"] == 4
+    for parent, children in _REGION_TREE.items():
+        p = by_name[parent]
+        for child in children:
+            c = by_name[child]
+            assert p.t0 <= c.t0 <= c.t1 <= p.t1, (parent, child)
+    assert call.t1 <= by_name["region.result"].t0
+    ids = {by_name[n].trace for n in ("serve.request", *_REGION_TREE,
+                                      *sum(_REGION_TREE.values(), ()))}
+    assert ids == {call.trace} and call.trace is not None
+    # call to bridge-out: only the caller's own flush lies outside them
+    cov = request_coverage(TRACER.chrome_events())[call.trace]
+    assert cov["spans"] == 8
+
+
+def test_every_interval_span_but_serve_request_reaches_profiler(
+        tmp_path, monkeypatch):
+    """With annotate on, every interval span of the served path is also
+    a profiler TraceAnnotation; serve.request, stamped after the fact
+    from its enqueue time, is the one that is not."""
+    entered = []
+
+    class StubAnnotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            entered.append(self.name)
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    mp_path = _bundle(tmp_path)
+    q = ServeQueue(FlushPolicy(max_batch_rows=1 << 30))
+    reg = _async_region(mp_path, q)
+    _region_round_trip(reg, q, mp_path)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", StubAnnotation)
+    enable_tracing(annotate=True)
+    TRACER.clear()
+    _region_round_trip(reg, q, mp_path)
+    recorded = {s.name for s in TRACER.events() if s.t1 > s.t0}
+    assert {"region.call", "region.result", "batch.scatter",
+            "serve.request"} <= recorded
+    assert recorded - {"serve.request"} == set(entered)
+    assert "serve.request" not in entered
+
+
+def test_untraced_region_call_records_nothing(tmp_path, monkeypatch):
+    """Tracing off: the region path records no span and mints no id."""
+    mp_path = _bundle(tmp_path)
+    q = ServeQueue(FlushPolicy(max_batch_rows=1 << 30))
+    reg = _async_region(mp_path, q)
+    disable_tracing()
+    TRACER.clear()
+
+    def no_id():
+        raise AssertionError("a trace id was minted with tracing off")
+
+    monkeypatch.setattr(TRACER, "new_trace_id", no_id)
+    handle = reg(x=_rows(4))
+    q.flush(mp_path)
+    assert handle.result(30)["y"].shape == (4, 1)
+    assert handle._future.trace is None
+    assert TRACER.events() == []
+
+
+def test_compile_listener_counts_new_shapes_only(tmp_path):
+    """One XLA compile for a batch shape the engine has not run, none for
+    a repeat; with tracing on each compile is an xla.compile span."""
+    from repro.core.engine import InferenceEngine
+    reg = default_registry()
+    compiles = reg.counter("repro_xla_compile_total",
+                           "XLA backend compiles in this process")
+    eng = InferenceEngine.get(_bundle(tmp_path, seed=3))
+    x = jax.device_put(_rows(8))
+    enable_tracing()
+    TRACER.clear()
+    before = compiles.value()
+    jax.block_until_ready(eng.apply_batched(x))
+    assert compiles.value() == before + 1
+    jax.block_until_ready(eng.apply_batched(x))
+    assert compiles.value() == before + 1
+    spans = [s for s in TRACER.events() if s.name == "xla.compile"]
+    assert len(spans) == 1
+    assert spans[0].args == {"fun": "jit(apply_fn)"}
+    assert spans[0].dur_s > 0
+
+
+def test_batched_apply_module_is_named_jit_apply_fn(tmp_path):
+    """The benchmark's apply_roofline finds the engine's apply in the
+    device trace by the XLA module name ``jit_apply_fn``."""
+    from repro.core.engine import InferenceEngine
+    from repro.dist.sharding import current_ctx
+    eng = InferenceEngine.get(_bundle(tmp_path, seed=4))
+    x = jax.device_put(_rows(8))
+    for donate in (False, True):
+        fn = eng._apply_for(current_ctx(), donate=donate)
+        hlo = fn.lower(eng.params, x).compile().as_text()
+        assert hlo.startswith("HloModule jit_apply_fn"), hlo[:80]
 
 
 def test_pod_flush_single_process_traced(tmp_path):
