@@ -157,7 +157,7 @@ def serve_sweeps(bundle, opts, *, callers: int = CALLERS,
     queue = ServeQueue(FlushPolicy(max_batch_rows=n, max_pending_rows=n))
     region = binomial.make_region(chunk, mode="infer_async",
                                   model=str(bundle), serving=queue)
-    _, rows = region._rows_in({"opts": opts[:chunk]})
+    rows = region._rows_in(region.engine(), {"opts": opts[:chunk]})
     require(Batcher._device_resident(rows),
             "bridged rows are not device-resident: the batcher would "
             "gather on the host instead of concatenating on device")

@@ -15,6 +15,10 @@ not served stale: ``get()`` re-reads a bundle whose on-disk fingerprint
 (mtime_ns + size) changed since load, and ``invalidate()``/``reload()``
 force it — retrain paths that bypass the fingerprint (exotic filesystems
 with coarse timestamps) should call ``invalidate()`` after writing.
+Freshness is checked where the engine serves: per call on the synchronous
+region path, per batch on the serve path (the batcher's ``get()``).  An
+async region call only reads the spec to shape its rows, so it looks the
+engine up with ``cached()``, which makes no file-system call.
 """
 from __future__ import annotations
 
@@ -47,8 +51,14 @@ def _mute_donation_warning_off_tpu():
 from repro.dist.sharding import constrain, current_ctx
 from repro.nn.serialize import load_model
 from repro.obs import TRACER, watch_compiles
+from repro.obs import metrics as _m
 
 watch_compiles()
+
+_FINGERPRINT_CHECKS = _m.counter(
+    "repro_engine_fingerprint_checks_total",
+    "get() comparisons of a cached engine with its bundle on disk",
+    ("bundle",))
 
 
 def bundle_norm(spec, net):
@@ -190,7 +200,6 @@ class InferenceEngine:
                 tuple(q) for q in quantize_params(weights, biases,
                                                   scale_mult=sm))
         self._qacts = tuple(acts)
-        from repro.obs import metrics as _m
         _m.counter("repro_quant_eligible_total",
                    "bundle loads that resolved to the int8 tier",
                    ("bundle",)).inc(1, bundle=self.path)
@@ -204,22 +213,35 @@ class InferenceEngine:
         this engine see the fresh weights.  ``trace`` is the trace id of
         a traced region call: the lookup is then its ``engine.get`` span.
         """
-        if trace is None:
-            return cls._get(str(model_path))
-        with TRACER.child("engine.get", trace, cat="engine"):
-            return cls._get(str(model_path))
+        return cls._lookup(str(model_path), trace, check=True)
 
     @classmethod
-    def _get(cls, key: str) -> "InferenceEngine":
+    def cached(cls, model_path, trace=None) -> "InferenceEngine":
+        """``get()`` without the on-disk fingerprint check: the cached
+        engine, loaded on a miss.  For callers that only read the spec
+        and leave serving to a path that calls ``get()`` itself."""
+        return cls._lookup(str(model_path), trace, check=False)
+
+    @classmethod
+    def _lookup(cls, key: str, trace, check: bool) -> "InferenceEngine":
+        if trace is None:
+            return cls._get(key, check)
+        with TRACER.child("engine.get", trace, cat="engine"):
+            return cls._get(key, check)
+
+    @classmethod
+    def _get(cls, key: str, check: bool) -> "InferenceEngine":
         with cls._cache_lock:
             eng = cls._cache.get(key)
             if eng is None:
                 eng = cls._cache[key] = cls(key)
-            elif _bundle_mtime(key) != eng._mtime:
-                # any fingerprint change reloads — including rollbacks to
-                # an older bundle (copy2/mv preserve the original, older
-                # mtime)
-                eng.reload()
+            elif check:
+                _FINGERPRINT_CHECKS.inc(1, bundle=key)
+                if _bundle_mtime(key) != eng._mtime:
+                    # any fingerprint change reloads — including
+                    # rollbacks to an older bundle (copy2/mv preserve
+                    # the original, older mtime)
+                    eng.reload()
         from repro.serve.residency import RESIDENCY
         RESIDENCY.touch(key)
         return eng
@@ -395,7 +417,6 @@ class InferenceEngine:
         fn = self._apply_for(ctx, donate=donate)
         x = self._place(x, ctx)
         if self.tier == "int8" and not isinstance(x, jax.core.Tracer):
-            from repro.obs import metrics as _m
             _m.counter("repro_quant_served_rows_total",
                        "rows served by the gated int8 tier",
                        ("bundle",)).inc(n, bundle=self.path)

@@ -184,23 +184,24 @@ class MLRegion:
     # ------------------------------------------------------- execution ----
     def engine(self, trace: Optional[str] = None) -> InferenceEngine:
         assert self.model_path, f"region {self.name}: no model path"
-        # always resolve through the process-wide cache: get() is a dict
-        # lookup + bundle-mtime stat, and it is what reloads a bundle the
-        # NAS loop retrained under this region's feet
+        # always resolve through the process-wide cache.  Freshness is
+        # checked where the engine serves: get() compares the bundle's
+        # on-disk fingerprint, and reloads a bundle the NAS loop retrained
+        # under this region's feet, on every sync call; on the serve path
+        # the batcher's get() does so once per batch
         return InferenceEngine.get(self.model_path, trace)
 
-    def _rows_in(self, arrays: dict, trace: Optional[str] = None,
-                 args: Optional[dict] = None):
-        """Bridge app arrays to engine-shaped f32 rows [n, *in_shape[1:]].
+    def _rows_in(self, eng: InferenceEngine, arrays: dict,
+                 trace: Optional[str] = None, args: Optional[dict] = None):
+        """Bridge app arrays to ``eng``-shaped f32 rows [n, *in_shape[1:]].
         A traced call passes its trace id and its span's ``args``."""
-        eng = self.engine(trace)
         in_shape = tuple(eng.spec["in_shape"])
         with TRACER.child("region.bridge_in", trace, cat="region"):
             X = self._bridge_in_jit(arrays)
             X = X.reshape((-1,) + in_shape[1:]).astype(jnp.float32)
         if args is not None:
             args["rows"] = int(X.shape[0])
-        return eng, X
+        return X
 
     def _call_span(self, body, arrays: dict):
         """``body(arrays, trace, args)`` inside this eager call's
@@ -234,7 +235,8 @@ class MLRegion:
         if use_breaker and not BREAKERS.allow(self.model_path):
             return self._fallback(arrays, "infer")
         try:
-            eng, Xb = self._rows_in(arrays, trace, args)
+            eng = self.engine(trace)
+            Xb = self._rows_in(eng, arrays, trace, args)
             Y = eng(Xb)
         except Exception:
             if not use_breaker:
@@ -268,8 +270,13 @@ class MLRegion:
             return AsyncRegionResult(
                 self, arrays,
                 resolved=self._fallback(arrays, "infer_async"))
-        eng, Xb = self._rows_in(arrays, trace, args)
-        del eng  # resolved for bundle load/reload; batcher re-gets per batch
+        # only the spec's in_shape is read here: the batcher's per-batch
+        # get() checks the bundle on disk and decides which weights serve
+        # these rows.  A retrain that changes in_shape between that check
+        # and this call shapes the rows by the cached spec, the same race
+        # as a retrain between submit and flush
+        eng = InferenceEngine.cached(self.model_path, trace)
+        Xb = self._rows_in(eng, arrays, trace, args)
         fut = self.serving.submit(self.model_path, Xb, trace=trace)
         if SHADOW.enabled and SHADOW.sample():
             self._shadow_submit(arrays, rows=int(Xb.shape[0]), future=fut)

@@ -1,6 +1,7 @@
 """repro.serve: queue/batcher flush policies, bucket padding round-trip,
 multiplexed regions, deadline determinism, stats, backpressure — plus the
 engine's bucketed apply + sharding-resolution cache it rides on."""
+import os
 import time
 
 import jax
@@ -400,6 +401,91 @@ def test_predicated_region_serving_defers(tmp_path):
     np.testing.assert_array_equal(
         np.asarray(h_ml.result(1)["out"]),
         np.asarray(_region(8, "infer", mp)(x=x)["out"]))
+
+
+def _fingerprint_checks(mp) -> float:
+    from repro.obs.metrics import default_registry
+    return default_registry().counter(
+        "repro_engine_fingerprint_checks_total", "",
+        ("bundle",)).value(bundle=str(mp))
+
+
+def test_bundle_fingerprint_checked_once_per_batch(tmp_path, monkeypatch):
+    """Async region calls only read the spec and make no stat of the
+    bundle; the batcher's per-batch get() checks it once per flush.  A
+    sync region serves with the engine it looks up, so it checks per
+    call."""
+    from repro.core import engine as engine_mod
+    mp = _lin_bundle(tmp_path)
+    InferenceEngine.get(mp)  # loaded before counting: a miss stats too
+    stats = []
+    real = engine_mod._bundle_mtime
+    monkeypatch.setattr(engine_mod, "_bundle_mtime",
+                        lambda path: stats.append(path) or real(path))
+    q = ServeQueue(FlushPolicy(max_batch_rows=1 << 20))
+    r = _region(8, "infer_async", mp, serving=q)
+    before = _fingerprint_checks(mp)
+    handles = [r(x=_rows(8, seed=s)) for s in range(8)]
+    assert stats == [] and _fingerprint_checks(mp) == before
+    q.flush()
+    for h in handles:
+        assert h.result(5)["out"].shape == (8, 1)
+    assert stats == [str(mp)]
+    assert _fingerprint_checks(mp) == before + 1
+    r_sync = _region(8, "infer", mp)
+    for s in range(3):
+        r_sync(x=_rows(8, seed=s))
+    assert _fingerprint_checks(mp) == before + 4
+    assert len(stats) == 4
+
+
+def test_async_region_serves_rewritten_bundle(tmp_path):
+    """The serve path still sees a bundle rewritten on disk: a step after
+    the rewrite, and rows submitted before it and flushed after, get the
+    new weights from the same engine, refreshed in place."""
+    net = MLP((1, 2), [16], 1)
+    p0 = net.init(jax.random.PRNGKey(0))
+    mp = save_model(tmp_path / "m", net, p0)
+    future = [os.path.getmtime(tmp_path / "m" / "params.npz")]
+
+    def rewrite(scale):
+        params = jax.tree.map(lambda w: w * scale, p0)
+        save_model(tmp_path / "m", net, params)
+        future[0] += 5  # past the filesystem's timestamp granularity
+        for f in ("spec.json", "params.npz"):
+            os.utime(tmp_path / "m" / f, (future[0], future[0]))
+        return params
+
+    def served(handle):
+        return np.asarray(handle.result(5)["out"])
+
+    q = ServeQueue(FlushPolicy(max_batch_rows=1 << 20))
+    r = _region(8, "infer_async", mp, serving=q)
+    x = _rows(8, seed=7)
+    h = r(x=x)
+    q.flush()
+    y0 = served(h)
+    eng = InferenceEngine.get(mp)
+    np.testing.assert_allclose(y0, np.asarray(net.apply(p0, x)),
+                               rtol=1e-5, atol=1e-6)
+    # rewritten between two steps
+    p1 = rewrite(3.0)
+    h = r(x=x)
+    q.flush()
+    y1 = served(h)
+    np.testing.assert_allclose(y1, np.asarray(net.apply(p1, x)),
+                               rtol=1e-5, atol=1e-6)
+    assert float(np.abs(y1 - y0).max()) > 1e-3
+    assert InferenceEngine.get(mp) is eng
+    # submitted before the rewrite, flushed after it
+    h = r(x=x)
+    p2 = rewrite(5.0)
+    q.flush()
+    y2 = served(h)
+    np.testing.assert_allclose(y2, np.asarray(net.apply(p2, x)),
+                               rtol=1e-5, atol=1e-6)
+    assert float(np.abs(y2 - y1).max()) > 1e-3
+    assert InferenceEngine.get(mp) is eng
 
 
 # ----------------------------------------------------------- app drivers ---
