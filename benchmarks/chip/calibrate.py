@@ -15,6 +15,7 @@ limit is the largest of the first, the upper the smallest of the second
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import pathlib
@@ -33,23 +34,21 @@ def seed_list(text: str) -> list:
     return out
 
 
-def control_reading(cell: dict, seed: int) -> float:
+def control_reading(cell: dict, forward, seed: int) -> float:
+    """The control's widest gap over the inputs of as many steps as a run
+    compares; ``forward`` is the cell's architecture's with its
+    configuration bound, one object for every seed."""
     import numpy as np
 
-    import generate
     import reference
-    config, traffic = cell["config"], cell["traffic"]
-    model = {"layers": generate.make_weights(config["widths"], seed),
-             "norm": generate.norm_stats(config)}
-    inputs = generate.make_inputs(config, traffic, seed)
-    gap = 0.0
-    for xs in inputs[:int(traffic["sampled_steps"])]:
-        x = np.concatenate([np.asarray(a) for a in xs])
-        ref = reference.run(model, x, activation=config["activation"])
-        ctl = reference.run(model, x, activation=config["activation"],
-                            precision="3pass")
-        gap = max(gap, reference.max_rel_err(ctl, ref))
-    return gap
+    config, traffic, arch = cell["config"], cell["traffic"], cell["arch"]
+    model = arch.make_weights(config, seed)
+    inputs = arch.make_inputs(config, traffic, seed)
+    x = [np.concatenate([np.asarray(a) for a in xs])
+         for xs in inputs[:int(traffic["sampled_steps"])]]
+    ref = reference.run(forward, model, x)
+    ctl = reference.run(forward, model, x, precision="3pass")
+    return max(reference.max_rel_err(c, r) for c, r in zip(ctl, ref))
 
 
 def main(argv=None) -> int:
@@ -75,9 +74,10 @@ def main(argv=None) -> int:
         print(json.dumps({"program_seed": seed, "correct": out["correct"],
                           "checks": out["checks"],
                           "steps": out["window"]["steps"]}), flush=True)
+    forward = functools.partial(cell["arch"].forward, cell["config"])
     for seed in seed_list(args.control_seeds) if args.control_seeds else []:
-        print(json.dumps({"control_seed": seed,
-                          "max_rel_err": control_reading(cell, seed)}),
+        print(json.dumps({"control_seed": seed, "max_rel_err":
+                          control_reading(cell, forward, seed)}),
               flush=True)
     return 0
 

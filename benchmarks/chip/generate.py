@@ -1,6 +1,8 @@
-"""Everything a run makes from its seed: the surrogate's weights, in one
-jitted call on the device, and the callers' inputs, drawn on the host in
-one block and placed on the device in one transfer.
+"""What every architecture makes from its seed alike: the seed's words,
+the callers' inputs with each feature uniform over its range, and the
+normalization a bundle trained on those ranges carries.  An
+architecture's weights are its own (``archs/<arch>.py``), made in one
+jitted call on the device and keyed by ``seed_words``.
 
 The seed may be any whole number, wider than 32 bits too: it is hashed
 (``numpy.random.SeedSequence``) to two 32-bit words, which key JAX's
@@ -11,11 +13,9 @@ ranges come from the configuration and traffic files.
 """
 from __future__ import annotations
 
-import functools
 import math
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 
@@ -25,24 +25,6 @@ def _seed_sequence(seed: int) -> np.random.SeedSequence:
 
 def seed_words(seed: int) -> np.ndarray:
     return _seed_sequence(seed).generate_state(2, np.uint32)
-
-
-@functools.partial(jax.jit, static_argnums=0)
-def _weights(widths, words):
-    key = jax.random.wrap_key_data(words, impl="threefry2x32")
-    layers = []
-    for i, (a, b) in enumerate(zip(widths[:-1], widths[1:])):
-        kw, kb = jax.random.split(jax.random.fold_in(key, i))
-        w = jax.random.normal(kw, (a, b), jnp.float32) * math.sqrt(2.0 / a)
-        layers.append((w, jax.random.normal(kb, (b,), jnp.float32) * 0.1))
-    return layers
-
-
-def make_weights(widths, seed: int):
-    """[(w [a, b], b [b]), ...] f32 on the default device: He-normal
-    weights and N(0, 0.1) biases."""
-    return _weights(tuple(int(w) for w in widths),
-                    jnp.asarray(seed_words(seed)))
 
 
 def feature_ranges(config):

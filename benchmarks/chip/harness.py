@@ -2,12 +2,17 @@
 drive its closed loop through the program's region path, read its
 metrics, and decide ``correct``.
 
-Everything that belongs to one configuration, traffic mix or metric is a
-file found by its name (``BENCHMARK.json`` names them):
+Everything that belongs to one architecture, configuration, traffic mix
+or metric is a file found by its name (``BENCHMARK.json`` names them):
 
-- ``configs/<config>.json``: the surrogate's widths, activation, tier,
-  region declaration, input features and their ranges, and the limit of
-  the comparison;
+- ``configs/<config>.json``: the surrogate's ``arch`` and its sizes,
+  tier, region declaration, input features and their ranges, and the
+  limit of the comparison;
+- ``archs/<arch>.py``, named by the configuration's ``arch``: the
+  functions of ``ARCH_API``, which make the seeded weights, the bundle
+  the program loads, the callers' inputs, the f32 forward pass of the
+  reference and the algorithm's FLOPs and bytes.  Nothing else varies by
+  architecture;
 - ``traffic/<traffic>.json``: loop kind, callers per step, rows per
   caller, distinct steps, steps sampled for the comparison;
 - ``metrics/<metric>.py``: a ``read(rec)`` that returns the metric's
@@ -23,6 +28,7 @@ counters; weights, inputs, the reference and the comparison are its own.
 from __future__ import annotations
 
 import contextlib
+import functools
 import gc
 import importlib.util
 import json
@@ -37,7 +43,6 @@ import time
 import jax
 import numpy as np
 
-import generate
 import reference
 import trace_reduce
 
@@ -48,6 +53,20 @@ WARMUP_STEPS = 2          # the first compiles (or loads the cache)
 RESULT_TIMEOUT_S = 60.0   # a caller waits this long for its rows
 TRACE_SECONDS = 4.0       # longest traced window of a --trace 1 run
 TRACE_RING = 1 << 18      # program tracer entries per thread
+
+
+#: what ``archs/<arch>.py`` provides:
+#: ``make_weights(config, seed) -> model``, the seeded weights and
+#: normalization the reference and the bundle both use;
+#: ``write_bundle(path, config, model) -> str``, the bundle the program
+#: loads by path; ``make_inputs(config, traffic, seed)``, every caller's
+#: rows at every distinct step, ``inputs[step][caller]``;
+#: ``forward(config, model, x, dot)``, the f32 forward pass with every
+#: product through ``dot`` (``reference.DOTS``);
+#: ``flops_per_row(config) -> int`` and ``call_bytes(config, rows) ->
+#: int``, the algorithm's counts (``work.py`` gives the rule)
+ARCH_API = ("make_weights", "write_bundle", "make_inputs", "forward",
+            "flops_per_row", "call_bytes")
 
 
 class NoChip(RuntimeError):
@@ -64,16 +83,29 @@ def load_part(kind: str, name: str, base=HERE) -> dict:
                       .read_text())
 
 
-def load_reader(name: str, base=HERE):
-    """``read`` of ``metrics/<name>.py``."""
-    path = pathlib.Path(base) / "metrics" / f"{name}.py"
-    mod_name = "chipbench_metric_" + re.sub(r"\W", "_", name)
-    spec = importlib.util.spec_from_file_location(mod_name, path)
-    if spec is None:
+def _load_module(kind: str, name: str, base):
+    path = pathlib.Path(base) / kind / f"{name}.py"
+    if not path.is_file():
         raise FileNotFoundError(path)
+    mod_name = f"chipbench_{kind}_" + re.sub(r"\W", "_", name)
+    spec = importlib.util.spec_from_file_location(mod_name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(name: str, base=HERE):
+    """``read`` of ``metrics/<name>.py``."""
+    return _load_module("metrics", name, base).read
+
+
+def load_arch(name: str, base=HERE):
+    """The module ``archs/<name>.py``, which provides ``ARCH_API``."""
+    mod = _load_module("archs", name, base)
+    missing = [f for f in ARCH_API if not callable(getattr(mod, f, None))]
+    if missing:
+        raise AttributeError(f"archs/{name}.py lacks {missing}")
+    return mod
 
 
 def find_cell(name: str, bench: dict, base=HERE) -> dict:
@@ -86,8 +118,11 @@ def find_cell(name: str, bench: dict, base=HERE) -> dict:
     def applies(m):
         return "workloads" not in m or name in m["workloads"]
 
-    return {"workload": w,
-            "config": load_part("configs", w["config"], base),
+    config = load_part("configs", w["config"], base)
+    if "arch" not in config:
+        raise KeyError(f"configuration {w['config']!r} names no \"arch\"")
+    return {"workload": w, "base": base, "config": config,
+            "arch": load_arch(config["arch"], base),
             "traffic": load_part("traffic", w["traffic"], base),
             "end_to_end": [m for m in bench["end_to_end"] if applies(m)],
             "per_layer": [m for m in bench["per_layer"] if applies(m)]}
@@ -105,27 +140,6 @@ def require_devices(chips: int) -> list:
 
 
 # --------------------------------------------------------------- set-up ---
-def write_bundle(path, config, layers, norm) -> str:
-    """The seeded weights as a model bundle the program loads by path,
-    with the normalization entries a trained bundle carries."""
-    from repro.nn.layers import MLP, Dense
-    from repro.nn.serialize import save_model
-    widths = config["widths"]
-    net = MLP((1, widths[0]), widths[1:-1], widths[-1],
-              act=config["activation"])
-    it = iter(layers)
-    params = []
-    for layer in net.layers:
-        if isinstance(layer, Dense):
-            w, b = next(it)
-            params.append({"w": w, "b": b})
-        else:
-            params.append({})
-    extra = {k: np.asarray(v).tolist()
-             for k, v in zip(("x_mu", "x_sd", "y_mu", "y_sd"), norm)}
-    return save_model(path, net, params, extra=extra)
-
-
 def _no_accurate_path(**arrays):
     raise RuntimeError("the accurate path is not part of the benchmark: a "
                        "call the surrogate did not serve counts as failed")
@@ -282,6 +296,7 @@ def run_cell(cell: dict, *, seed: int, seconds: float, trace: bool,
     from repro.serve import FlushPolicy, ServeQueue
 
     config, traffic, w = cell["config"], cell["traffic"], cell["workload"]
+    arch = cell["arch"]
     if traffic["loop"] != "closed":
         raise ValueError(f"loop {traffic['loop']!r} is not implemented")
     if config["matmul_precision"] != "highest":
@@ -293,7 +308,6 @@ def run_cell(cell: dict, *, seed: int, seconds: float, trace: bool,
     devices = devices[:chips]
     callers = int(traffic["callers"])
     rows_step = callers * int(traffic["rows_per_caller"])
-    widths = tuple(config["widths"])
 
     tmp = pathlib.Path(tempfile.mkdtemp(prefix="chipbench-"))
     stack = contextlib.ExitStack()
@@ -304,14 +318,11 @@ def run_cell(cell: dict, *, seed: int, seconds: float, trace: bool,
         phases[name] = time.perf_counter() - t_start - sum(phases.values())
 
     try:
-        layers = generate.make_weights(widths, seed)
-        norm = generate.norm_stats(config)
-        model = {"layers": jax.device_get(layers), "norm": norm}
-        del layers
+        model = arch.make_weights(config, seed)
         phase("weights")
-        bundle = write_bundle(tmp / "bundle", config, model["layers"], norm)
+        bundle = arch.write_bundle(tmp / "bundle", config, model)
         phase("bundle")
-        inputs = generate.make_inputs(config, traffic, seed)
+        inputs = arch.make_inputs(config, traffic, seed)
         jax.block_until_ready(inputs)
         phase("inputs")
         queue = ServeQueue(FlushPolicy(max_batch_rows=2 * rows_step,
@@ -398,7 +409,8 @@ def run_cell(cell: dict, *, seed: int, seconds: float, trace: bool,
         InferenceEngine.invalidate(bundle)
         counts = _delta(_counter_totals(), before)
 
-        gap = _compare(config, model, kept, kept_x)
+        gap = _compare(functools.partial(arch.forward, config), model,
+                       kept, kept_x)
         reduced = None
         if trace:
             xplane = _xplane(tmp / "trace")
@@ -428,14 +440,17 @@ def run_cell(cell: dict, *, seed: int, seconds: float, trace: bool,
             if k.endswith("vmem-fallback")), "limit": 0},
     }
     rec = {"config": config, "traffic": traffic, "chips": chips,
-           "widths": widths, "rows_per_step": rows_step,
+           "rows_per_step": rows_step,
+           "flops_per_row": arch.flops_per_row(config),
+           # one apply call at the rows one chip serves
+           "call_bytes": arch.call_bytes(config, rows_step // chips),
            "device_kind": devices[0].device_kind,
            "setup_s": setup_s, "window_s": t_w1 - t_w0, "rows": rows,
            "steps": steps, "spans": spans, "trace": reduced}
     wanted = cell["per_layer"] if trace else cell["end_to_end"]
     metrics = {}
     for m in wanted:
-        value = load_reader(m["name"])(rec)
+        value = load_reader(m["name"], cell["base"])(rec)
         if value is not None:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
     device = {"platform": devices[0].platform,
@@ -464,12 +479,12 @@ def run_cell(cell: dict, *, seed: int, seconds: float, trace: bool,
     return out
 
 
-def _compare(config, model, kept, kept_x) -> float:
+def _compare(forward, model, kept, kept_x) -> float:
     """The widest relative gap over the sampled steps: each step's rows,
-    as every caller got them back, against the reference over the same
-    step's inputs."""
-    refs = {p: reference.run(model, x, activation=config["activation"])
-            for p, x in kept_x.items()}
+    as every caller got them back, against the reference (``forward``,
+    the architecture's with the configuration bound) over the same step's
+    inputs."""
+    refs = dict(zip(kept_x, reference.run(forward, model, kept_x.values())))
     gap = 0.0
     for p, outs in kept:
         if any(o is None for o in outs):

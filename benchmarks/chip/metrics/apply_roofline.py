@@ -2,8 +2,9 @@
 engine's apply program (the XLA module ``jit_apply_fn``, whatever kernel
 runs inside it), in percent.  The least time of a call is the larger of
 its FLOPs over the chip's peak FLOP/s and its bytes over the peak bytes/s
-(``work.py``), at the net's widths and the rows the call served on that
-chip, not the padded ones."""
+(``work.py``), from the architecture's counts at the rows the call served
+on that chip, not the padded ones (``flops_per_row`` and ``call_bytes``
+of the run's record)."""
 import trace_reduce
 import work
 
@@ -19,5 +20,6 @@ def read(rec):
         return None
     rows = rec["rows_per_step"] // rec["chips"]
     peak = work.peak_for(rec["device_kind"])
-    least = calls * work.least_time_s(rec["widths"], rows, peak)
+    least = calls * work.least_time_s(rec["flops_per_row"] * rows,
+                                      rec["call_bytes"], peak)
     return 100.0 * least / secs

@@ -1,18 +1,19 @@
-"""The plain reference of a served MLP surrogate, its control, and the
+"""The plain reference of a served surrogate, its control, and the
 comparison that decides ``correct``.
 
-The reference is the surrogate's forward pass written out in
-``jax.numpy``: normalize the inputs, a chain of dense layers with the
-activation between them, denormalize the outputs.  Every product runs in
-f32 at ``highest`` matmul precision, so on a TPU it is f32 arithmetic and
-not one bf16 pass.  It imports nothing of the program and is given the
-weights the benchmark made from the seed, never a bundle read back.
+The reference is the architecture's forward pass written out in
+``jax.numpy`` (``forward`` of ``archs/<arch>.py``).  Every product runs
+through the ``dot`` this module hands it, in f32 at ``highest`` matmul
+precision, so on a TPU it is f32 arithmetic and not one bf16 pass.  It
+imports nothing of the program and is given the weights the benchmark
+made from the seed, never a bundle read back.
 
 The control is the same forward pass one precision step lower: each f32
 product split into three bf16 passes (high x high + high x low + low x
 high), what a matmul at ``high`` precision computes.  It is the step a
 later change to the served kernel would be tempted to take, and the
 comparison's limit sits between the two (``PERF.md`` gives the readings).
+The reference and its control are one code path; only ``dot`` differs.
 """
 from __future__ import annotations
 
@@ -23,7 +24,6 @@ import jax.numpy as jnp
 import numpy as np
 
 BLOCK_ROWS = 65536
-_ACTS = {"relu": lambda h: jnp.maximum(h, 0.0)}
 
 
 def _dot(a, b):
@@ -55,38 +55,23 @@ def _dot_3pass(a, b):
 DOTS = {"highest": _dot, "3pass": _dot_3pass}
 
 
-def forward(model, x, *, activation="relu", precision="highest"):
-    """``model``: {"layers": [(w, b), ...], "norm": (x_mu, x_sd, y_mu,
-    y_sd)}; ``x``: [rows, in] f32.  Returns [rows, out] f32."""
-    dot = DOTS[precision]
-    act = _ACTS[activation]
-    x_mu, x_sd, y_mu, y_sd = model["norm"]
-    h = (x - x_mu) / x_sd
-    layers = model["layers"]
-    for i, (w, b) in enumerate(layers):
-        h = dot(h, w) + b
-        if i + 1 < len(layers):
-            h = act(h)
-    return h * y_sd + y_mu
-
-
-@functools.lru_cache(maxsize=None)
-def _jitted(activation: str, precision: str):
-    return jax.jit(functools.partial(forward, activation=activation,
-                                     precision=precision))
-
-
-def run(model, x, *, activation="relu", precision="highest",
-        block_rows=BLOCK_ROWS) -> np.ndarray:
-    """The reference (or, at ``precision="3pass"``, the control) over
-    ``x`` in blocks of rows, so that it fits beside whatever else is
-    resident."""
-    f = _jitted(activation, precision)
-    x = np.asarray(x, np.float32)
+def run(forward, model, xs, *, precision="highest",
+        block_rows=BLOCK_ROWS) -> list:
+    """The reference (or, at ``precision="3pass"``, the control) over each
+    array of ``xs``, in blocks of rows so that it fits beside whatever
+    else is resident; one array of outputs for each.  ``forward(model, x,
+    dot)`` is an architecture's forward pass with its configuration bound
+    (``functools.partial(arch.forward, config)``), compiled once for the
+    call."""
+    f = jax.jit(functools.partial(forward, dot=DOTS[precision]))
+    outs = []
     with jax.default_matmul_precision("highest"):
-        outs = [np.asarray(f(model, jnp.asarray(x[i:i + block_rows])))
-                for i in range(0, x.shape[0], block_rows)]
-    return np.concatenate(outs, axis=0)
+        for x in xs:
+            x = np.asarray(x, np.float32)
+            outs.append(np.concatenate(
+                [np.asarray(f(model, jnp.asarray(x[i:i + block_rows])))
+                 for i in range(0, x.shape[0], block_rows)], axis=0))
+    return outs
 
 
 def max_rel_err(served, ref) -> float:

@@ -1,7 +1,7 @@
 """Run one benchmark cell once on the chips of this machine.
 
     python3 benchmarks/chip/run.py --workload binomial-ranks --seed 7 \\
-        --seconds 30 --trace 0
+        --seconds 51 --trace 0
 
 Sets the cell up from the seed, warms up its shapes, measures for
 ``--seconds`` seconds, checks the rows served against the benchmark's own

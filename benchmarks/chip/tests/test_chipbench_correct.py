@@ -2,6 +2,7 @@
 reference one precision step lower) fails each configuration's limit
 while the program passes it, and a run whose timed path is broken
 underneath the harness comes out not correct."""
+import functools
 import json
 import os
 import pathlib
@@ -17,7 +18,6 @@ CHIP = pathlib.Path(__file__).resolve().parents[1]
 ROOT = CHIP.parents[1]
 sys.path[:0] = [str(CHIP), str(ROOT / "src")]
 
-import generate  # noqa: E402
 import harness  # noqa: E402
 import reference  # noqa: E402
 
@@ -28,22 +28,29 @@ CONFIGS = [c["name"] for c in BENCH["configs"]]
 @pytest.mark.parametrize("name", CONFIGS)
 def test_control_fails_the_limit_and_the_reference_path_passes(name):
     config = harness.load_part("configs", name)
+    arch = harness.load_arch(config["arch"])
+    forward = functools.partial(arch.forward, config)
     limit = config["check"]["max_rel_err"]
     traffic = {"distinct_steps": 1, "callers": 1, "rows_per_caller": 2048}
     for seed in (1, 2, 3):
-        model = {"layers": generate.make_weights(config["widths"], seed),
-                 "norm": generate.norm_stats(config)}
-        x = np.asarray(generate.make_inputs(config, traffic, seed)[0][0])
-        ref = reference.run(model, x)
-        ctl = reference.run(model, x, precision="3pass")
+        model = arch.make_weights(config, seed)
+        x = np.asarray(arch.make_inputs(config, traffic, seed)[0][0])
+        ref, = reference.run(forward, model, [x])
+        ctl, = reference.run(forward, model, [x], precision="3pass")
         assert reference.max_rel_err(ctl, ref) > limit
         # the same reference in blocks of rows reads well inside the limit
-        blocked = reference.run(model, x, block_rows=512)
+        blocked, = reference.run(forward, model, [x], block_rows=512)
         assert reference.max_rel_err(blocked, ref) < limit / 4
 
 
+CELLS = [w["name"] for w in BENCH["workloads"]]
+
+
 def _tiny_cell(name="minibude-bulk", callers=4):
+    """The cell's own architecture, configuration and traffic at a tiny
+    size on one device."""
     cell = harness.find_cell(name, BENCH)
+    cell["workload"] = dict(cell["workload"], chips=1)
     cell["traffic"] = dict(cell["traffic"], callers=callers,
                            rows_per_caller=64, distinct_steps=2,
                            sampled_steps=2)
@@ -57,8 +64,9 @@ def _run(cell, seed=5):
                             devices=jax.devices(), log=sys.stderr)
 
 
-def test_a_sound_run_is_correct():
-    out = _run(_tiny_cell())
+@pytest.mark.parametrize("name", CELLS)
+def test_a_sound_run_is_correct(name):
+    out = _run(_tiny_cell(name))
     assert out["correct"], out["checks"]
     assert out["attempted"] > 0 and out["failed"] == 0
     assert set(out["metrics"]) == {"rows_per_s", "step_ms_p95", "setup_s"}
@@ -66,24 +74,12 @@ def test_a_sound_run_is_correct():
     assert list(out)[-1] == "checks"
 
 
-def _alter_one_answer(y):
-    return y.at[0].add(0.01)
-
-
-def _leave_out_half(y):
-    return y.at[y.shape[0] // 2:].set(0.0)
-
-
-@pytest.mark.parametrize("fault", [_alter_one_answer, _leave_out_half])
-def test_a_broken_engine_output_is_not_correct(monkeypatch, fault):
-    from repro.core.engine import InferenceEngine
-    apply = InferenceEngine.apply_batched
-
-    def broken(self, x, **kw):
-        return fault(apply(self, x, **kw))
-
-    monkeypatch.setattr(InferenceEngine, "apply_batched", broken)
-    out = _run(_tiny_cell())
+@pytest.mark.parametrize("fault", ["alter_one_answer",
+                                   "leave_out_half"])
+@pytest.mark.parametrize("name", CELLS)
+def test_a_broken_engine_output_is_not_correct(break_engine, name, fault):
+    break_engine(fault)
+    out = _run(_tiny_cell(name))
     assert not out["correct"]
     assert out["checks"]["max_rel_err"]["value"] > \
         out["checks"]["max_rel_err"]["limit"]

@@ -2,12 +2,14 @@
 traffic mixes and metric readers found by name, seeded traffic, the
 reference against the program's own forward pass, and no result without
 a TPU."""
+import functools
 import json
 import os
 import pathlib
 import shutil
 import subprocess
 import sys
+import time
 
 import numpy as np
 import pytest
@@ -31,7 +33,7 @@ def test_every_cell_resolves_to_its_files():
         assert cell["config"]["name"] == w["config"]
         assert configs[w["config"]]["file"] == \
             f"benchmarks/chip/configs/{w['config']}.json"
-        assert cell["config"]["widths"][0] == len(cell["config"]["features"])
+        assert cell["arch"].flops_per_row(cell["config"]) > 0
         assert {m["name"] for m in cell["end_to_end"]} >= {"setup_s"}
         for m in cell["end_to_end"] + cell["per_layer"]:
             assert callable(harness.load_reader(m["name"]))
@@ -42,7 +44,85 @@ def test_unknown_cell_raises():
         harness.find_cell("no-such-cell", BENCH)
 
 
-def test_new_files_are_found_with_no_edit_elsewhere(tmp_path):
+# an architecture that is not a chain of dense layers: dense, LayerNorm,
+# relu, dense, which the engine serves on its XLA path (not fused_mlp)
+TOY_ARCH = '''"""Dense layers with a LayerNorm between them."""
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+import generate
+import work
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _weights(widths, words):
+    a, h, o = widths
+    k = jax.random.split(jax.random.wrap_key_data(words), 6)
+    return {"w1": jax.random.normal(k[0], (a, h)) * (2.0 / a) ** 0.5,
+            "b1": jax.random.normal(k[1], (h,)) * 0.1,
+            "scale": 1.0 + 0.1 * jax.random.normal(k[2], (h,)),
+            "bias": 0.1 * jax.random.normal(k[3], (h,)),
+            "w2": jax.random.normal(k[4], (h, o)) * (2.0 / h) ** 0.5,
+            "b2": jax.random.normal(k[5], (o,)) * 0.1}
+
+
+def make_weights(config, seed):
+    words = jnp.asarray(generate.seed_words(seed))
+    return {"params": jax.device_get(_weights(tuple(config["widths"]),
+                                              words)),
+            "norm": generate.norm_stats(config)}
+
+
+def write_bundle(path, config, model):
+    from repro.nn.layers import Activation, Dense, LayerNorm, Sequential
+    from repro.nn.serialize import save_model
+    a, h, o = config["widths"]
+    net = Sequential([Dense(h), LayerNorm(), Activation("relu"), Dense(o)],
+                     (1, a))
+    p = model["params"]
+    params = [{"w": p["w1"], "b": p["b1"]},
+              {"scale": p["scale"], "bias": p["bias"]}, {},
+              {"w": p["w2"], "b": p["b2"]}]
+    extra = {k: np.asarray(v).tolist()
+             for k, v in zip(("x_mu", "x_sd", "y_mu", "y_sd"), model["norm"])}
+    return save_model(path, net, params, extra=extra)
+
+
+def make_inputs(config, traffic, seed):
+    return generate.make_inputs(config, traffic, seed)
+
+
+def forward(config, model, x, dot):
+    p = model["params"]
+    x_mu, x_sd, y_mu, y_sd = model["norm"]
+    h = dot((x - x_mu) / x_sd, p["w1"]) + p["b1"]
+    mu = h.mean(-1, keepdims=True)
+    var = ((h - mu) ** 2).mean(-1, keepdims=True)
+    h = (h - mu) / jnp.sqrt(var + 1e-6) * p["scale"] + p["bias"]
+    h = jnp.maximum(h, 0.0)
+    return (dot(h, p["w2"]) + p["b2"]) * y_sd + y_mu
+
+
+def flops_per_row(config):
+    a, h, o = config["widths"]
+    return 2 * (a * h + h * o)
+
+
+def call_bytes(config, rows):
+    a, h, o = config["widths"]
+    params = a * h + h + 2 * h + h * o + o
+    return work.F32_BYTES * (params + rows * (a + o))
+'''
+
+
+def test_new_files_are_found_with_no_edit_elsewhere(tmp_path, break_engine):
+    """A new architecture, configuration, traffic mix, metric and cell
+    are new files and entries alone; the new architecture's cell runs
+    whole to ``correct: true``, and to false with one answer altered."""
+    import jax
     base = tmp_path / "chip"
     shutil.copytree(CHIP, base, ignore=shutil.ignore_patterns(
         "tests", "__pycache__"))
@@ -51,25 +131,70 @@ def test_new_files_are_found_with_no_edit_elsewhere(tmp_path):
          "distinct_steps": 2, "sampled_steps": 1}))
     (base / "metrics" / "steps_seen.py").write_text(
         "def read(rec):\n    return len(rec['steps']) or None\n")
+    (base / "archs" / "toy_ln.py").write_text(TOY_ARCH)
+    config = json.loads(
+        (CHIP / "configs" / "binomial-mlp-5-512-512-1.json").read_text())
+    config.update(name="toy-ln-5-32-1", arch="toy_ln", widths=[5, 32, 1])
+    del config["activation"]
+    (base / "configs" / "toy-ln-5-32-1.json").write_text(json.dumps(config))
     bench = json.loads(json.dumps(BENCH))
-    bench["workloads"].append({"name": "binomial-tiny",
-                               "config": "binomial-mlp-5-512-512-1",
-                               "traffic": "ranks-8x16", "chips": 1,
-                               "why": "test"})
+    bench["configs"].append({"name": "toy-ln-5-32-1", "source": "test",
+                             "file": "benchmarks/chip/configs/"
+                                     "toy-ln-5-32-1.json",
+                             "reduced": [], "why": "test"})
+    bench["workloads"] += [{"name": "binomial-tiny",
+                            "config": "binomial-mlp-5-512-512-1",
+                            "traffic": "ranks-8x16", "chips": 1,
+                            "why": "test"},
+                           {"name": "toy-ln-tiny", "config": "toy-ln-5-32-1",
+                            "traffic": "ranks-8x16", "chips": 1,
+                            "why": "test"}]
     bench["per_layer"].append({"name": "steps_seen", "unit": "1",
                                "better": "higher", "source": "host_clock",
                                "layer": "whole step", "moves": "rows_per_s",
-                               "workloads": ["binomial-tiny"]})
+                               "workloads": ["binomial-tiny", "toy-ln-tiny"]})
     cell = harness.find_cell("binomial-tiny", bench, base)
     assert cell["traffic"]["callers"] == 8
     assert [m["name"] for m in cell["per_layer"]] == ["steps_seen"]
     read = harness.load_reader("steps_seen", base)
     assert read({"steps": [(0, 1, 0)] * 3}) == 3
 
+    def run():
+        return harness.run_cell(harness.find_cell("toy-ln-tiny", bench, base),
+                                seed=2 ** 33 + 3, seconds=0.3, trace=False,
+                                t_start=time.perf_counter(),
+                                devices=jax.devices())
+
+    out = run()
+    assert out["correct"], out["checks"]
+    assert out["failed"] == 0 and out["attempted"] > 0
+    assert set(out["metrics"]) == {"rows_per_s", "step_ms_p95", "setup_s"}
+    break_engine("alter_one_answer")
+    out = run()
+    assert not out["correct"]
+    assert out["checks"]["max_rel_err"]["value"] > \
+        out["checks"]["max_rel_err"]["limit"]
+
+
+@pytest.mark.parametrize("arch,error", [(None, KeyError),
+                                        ("no_such_arch", FileNotFoundError)])
+def test_a_configuration_without_a_known_arch_raises(tmp_path, arch, error):
+    config = harness.load_part("configs", "binomial-mlp-5-512-512-1")
+    del config["arch"]
+    if arch is not None:
+        config["arch"] = arch
+    for kind in ("configs", "traffic"):
+        shutil.copytree(CHIP / kind, tmp_path / kind)
+    (tmp_path / "configs" / "binomial-mlp-5-512-512-1.json").write_text(
+        json.dumps(config))
+    with pytest.raises(error, match="arch"):
+        harness.find_cell("binomial-ranks", BENCH, tmp_path)
+
 
 def test_readers_return_nothing_where_there_is_nothing_to_read():
     rec = {"steps": [], "spans": None, "trace": None, "rows": 0,
-           "window_s": 0.0, "setup_s": 1.0, "widths": (5, 8, 1),
+           "window_s": 0.0, "setup_s": 1.0, "flops_per_row": 96,
+           "call_bytes": 4 * (57 + 8 * 6),
            "chips": 1, "rows_per_step": 8, "device_kind": "cpu"}
     for m in BENCH["per_layer"]:
         assert harness.load_reader(m["name"])(rec) is None, m["name"]
@@ -98,11 +223,11 @@ def test_reference_agrees_with_the_program_forward_pass(tmp_path):
     import jax
     from repro.core.engine import bundle_norm
     from repro.nn.serialize import load_model
-    config = harness.find_cell("binomial-ranks", BENCH)["config"]
-    config = dict(config, widths=[5, 32, 16, 1])
-    layers = jax.device_get(generate.make_weights(config["widths"], 3))
-    norm = generate.norm_stats(config)
-    bundle = harness.write_bundle(tmp_path / "b", config, layers, norm)
+    cell = harness.find_cell("binomial-ranks", BENCH)
+    arch = cell["arch"]
+    config = dict(cell["config"], widths=[5, 32, 16, 1])
+    model = arch.make_weights(config, 3)
+    bundle = arch.write_bundle(tmp_path / "b", config, model)
     net, params, spec = load_model(bundle)
     mu_x, sd_x, mu_y, sd_y = bundle_norm(spec, net)
     x = np.asarray(generate.make_inputs(
@@ -110,7 +235,8 @@ def test_reference_agrees_with_the_program_forward_pass(tmp_path):
         3)[0][0])
     with jax.default_matmul_precision("highest"):
         y = np.asarray(net.apply(params, (x - mu_x) / sd_x) * sd_y + mu_y)
-    ref = reference.run({"layers": layers, "norm": norm}, x)
+    ref, = reference.run(functools.partial(arch.forward, config), model,
+                         [x])
     assert ref.shape == (64, 1)
     assert reference.max_rel_err(y, ref) < 1e-6
 
