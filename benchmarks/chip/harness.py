@@ -6,8 +6,9 @@ Everything that belongs to one architecture, configuration, traffic mix
 or metric is a file found by its name (``BENCHMARK.json`` names them):
 
 - ``configs/<config>.json``: the surrogate's ``arch`` and its sizes,
-  tier, region declaration, input features and their ranges, and the
-  limit of the comparison;
+  tier, region declaration, input features and their ranges, the limits
+  of the comparison (``check``), and optionally the smaller sizes its
+  CPU tests run at (``cpu_size``, see ``cpu_config``);
 - ``archs/<arch>.py``, named by the configuration's ``arch``: the
   functions of ``ARCH_API``, which make the seeded weights, the bundle
   the program loads, the callers' inputs, the f32 forward pass of the
@@ -62,11 +63,19 @@ TRACE_RING = 1 << 18      # program tracer entries per thread
 #: loads by path; ``make_inputs(config, traffic, seed)``, every caller's
 #: rows at every distinct step, ``inputs[step][caller]``;
 #: ``forward(config, model, x, dot)``, the f32 forward pass with every
-#: product through ``dot`` (``reference.DOTS``);
+#: product through ``dot`` (``reference.DOTS``), which returns the
+#: outputs, or ``(outputs, defined)`` with one boolean a row that is False
+#: where the reference's own arithmetic leaves the row's answer undefined
+#: at f32 (a routing decision within a margin the configuration states);
 #: ``flops_per_row(config) -> int`` and ``call_bytes(config, rows) ->
 #: int``, the algorithm's counts (``work.py`` gives the rule)
 ARCH_API = ("make_weights", "write_bundle", "make_inputs", "forward",
             "flops_per_row", "call_bytes")
+
+
+#: keys a configuration's ``cpu_size`` may never replace
+CPU_FIXED = ("arch", "tier", "matmul_precision", "region", "features",
+             "check")
 
 
 class NoChip(RuntimeError):
@@ -126,6 +135,34 @@ def find_cell(name: str, bench: dict, base=HERE) -> dict:
             "traffic": load_part("traffic", w["traffic"], base),
             "end_to_end": [m for m in bench["end_to_end"] if applies(m)],
             "per_layer": [m for m in bench["per_layer"] if applies(m)]}
+
+
+def _whole_numbers(value) -> bool:
+    """A whole number, or a non-empty list of them."""
+    values = value if isinstance(value, list) else [value]
+    return bool(values) and all(
+        isinstance(v, int) and not isinstance(v, bool) for v in values)
+
+
+def cpu_config(config: dict) -> dict:
+    """The configuration as its CPU tests build and serve it: each key of
+    its optional ``cpu_size`` replaced by the value given there.
+    ``cpu_size`` may name only keys the configuration has whose values,
+    there and in ``cpu_size``, are whole numbers or lists of them (widths,
+    counts of experts or layers), and none of ``CPU_FIXED``.  A chip run
+    never reads it: ``find_cell`` and ``run_cell`` take the published
+    sizes."""
+    size = config.get("cpu_size", {})
+    for key, value in size.items():
+        if key in CPU_FIXED or key not in config or not (
+                _whole_numbers(config[key]) and _whole_numbers(value)):
+            raise ValueError(
+                f"cpu_size may not replace {key!r}: it replaces only whole "
+                f"numbers, or lists of them, that the configuration has, "
+                f"and never one of {CPU_FIXED}")
+    out = {k: v for k, v in config.items() if k != "cpu_size"}
+    out.update(size)
+    return out
 
 
 def require_devices(chips: int) -> list:
@@ -409,8 +446,10 @@ def run_cell(cell: dict, *, seed: int, seconds: float, trace: bool,
         InferenceEngine.invalidate(bundle)
         counts = _delta(_counter_totals(), before)
 
-        gap = _compare(functools.partial(arch.forward, config), model,
-                       kept, kept_x)
+        t_c0 = time.perf_counter()
+        gap, left_out = _compare(functools.partial(arch.forward, config),
+                                 model, kept, kept_x)
+        compare_s = time.perf_counter() - t_c0
         reduced = None
         if trace:
             xplane = _xplane(tmp / "trace")
@@ -431,6 +470,9 @@ def run_cell(cell: dict, *, seed: int, seconds: float, trace: bool,
     checks = {
         "max_rel_err": {"value": gap,
                         "limit": config["check"]["max_rel_err"]},
+        # a configuration that states no bound leaves no row out
+        "rows_left_out": {"value": left_out, "limit": config["check"].get(
+            "max_rows_left_out", 0)},
         "failed_calls": {"value": failed_calls, "limit": 0},
         "ref_dispatches": {"value": sum(
             v for k, v in counts["dispatch"].items() if k.endswith(":ref")),
@@ -472,6 +514,7 @@ def run_cell(cell: dict, *, seed: int, seconds: float, trace: bool,
                      # compute more slowly
                      "loop_cpu_s": cpu_w,
                      "setup_s": setup_s, "setup_phases": phases,
+                     "compare_s": compare_s,
                      "compiles": compiles,
                      "dispatch": counts["dispatch"],
                      "breaker_fallbacks": counts["fallback"]}
@@ -479,16 +522,18 @@ def run_cell(cell: dict, *, seed: int, seconds: float, trace: bool,
     return out
 
 
-def _compare(forward, model, kept, kept_x) -> float:
-    """The widest relative gap over the sampled steps: each step's rows,
-    as every caller got them back, against the reference (``forward``,
-    the architecture's with the configuration bound) over the same step's
-    inputs."""
+def _compare(forward, model, kept, kept_x) -> tuple:
+    """``(gap, left_out)`` over the sampled steps: each step's rows, as
+    every caller got them back, against the reference (``forward``, the
+    architecture's with the configuration bound) over the same step's
+    inputs (``reference.compare``): the widest relative gap on the rows
+    the reference defines, and the share of rows it leaves out."""
     refs = dict(zip(kept_x, reference.run(forward, model, kept_x.values())))
-    gap = 0.0
+    pairs = []
     for p, outs in kept:
         if any(o is None for o in outs):
-            return float("inf")
-        served = np.concatenate([o.reshape(o.shape[0], -1) for o in outs])
-        gap = max(gap, reference.max_rel_err(served, refs[p]))
-    return gap
+            return float("inf"), 0.0
+        pairs.append((np.concatenate([o.reshape(o.shape[0], -1)
+                                      for o in outs]), refs[p]))
+    gap, left_out, rows = reference.compare(pairs)
+    return gap, left_out / rows

@@ -72,7 +72,8 @@ def main(argv=None) -> int:
           f"{w['step_ms_max']:.1f} ms, loop CPU {w['loop_cpu_s']:.3f} s), "
           f"set-up {w['setup_s']:.3f} s ({phases}), compiles in the window "
           f"{w['compiles']}, dispatches {w['dispatch']}, breaker fallbacks "
-          f"{w['breaker_fallbacks']}", file=sys.stderr, flush=True)
+          f"{w['breaker_fallbacks']}, comparison {w['compare_s']:.3f} s",
+          file=sys.stderr, flush=True)
     for name, c in out["checks"].items():
         print(f"check {name} {c['value']!r} limit {c['limit']!r}",
               file=sys.stderr, flush=True)

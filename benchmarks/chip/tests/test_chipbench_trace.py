@@ -78,3 +78,23 @@ def test_reduce_a_window_recorded_on_the_chip():
                                   pytest.approx(0.001628208)]
     assert r["idle_gaps"][0] == ["bench.region", pytest.approx(0.098444643)]
     assert len(r["device_ops"]) == len(r["idle_gaps"]) == trace_reduce.TOP
+
+
+def _recorded():
+    return trace_reduce.extract(trace_reduce.load(
+        DATA / "binomial-ranks-2steps.xplane.pb.gz"))
+
+
+@pytest.mark.parametrize("make", [_hand_made, _recorded])
+def test_ops_keep_every_op_time(make):
+    """``ops`` holds every op name's seconds per chip: they sum to all the
+    op time in the window, and ``device_ops`` is their longest ten."""
+    ex = make()
+    r = trace_reduce.reduce(ex)
+    t0, t1 = trace_reduce.window_of(ex)
+    total = sum(min(b, t1) - max(a, t0) for d in ex["devices"].values()
+                for _, a, b in d["ops"] if b > t0 and a < t1)
+    assert sum(r["ops"].values()) == \
+        pytest.approx(total / len(ex["devices"]) * 1e-9, rel=1e-12)
+    longest = sorted(r["ops"].items(), key=lambda kv: -kv[1])
+    assert r["device_ops"] == [list(kv) for kv in longest[:trace_reduce.TOP]]

@@ -10,8 +10,9 @@ Two steps, so that a recorded trace can be checked on the CPU:
 - :func:`reduce` takes the window from the host event named
   ``bench.window`` and, inside it: the union of each device's op
   intervals (busy), the gaps between them, each gap's time charged to the
-  innermost host event around its middle, and the seconds per op name and
-  per program.  Device numbers are the mean over the devices traced.
+  innermost host event around its middle, and the seconds per op name
+  (every op in ``ops``, the longest in ``device_ops``) and per program.
+  Device numbers are the mean over the devices traced.
 """
 from __future__ import annotations
 
@@ -144,6 +145,8 @@ def reduce(ex: dict) -> dict:
         "busy_s": sum(busy) / n_dev * 1e-9,
         "devices": n_dev,
         "modules": modules,
+        # every op name, so that a reader can sum one kernel's instances
+        "ops": {k: v / n_dev * 1e-9 for k, v in op_s.items()},
         "device_ops": [[k, v * 1e-9] for k, v in _top(op_s, n_dev)],
         "idle_gaps": [[k, v * 1e-9] for k, v in _top(idle_by_host, n_dev)],
     }
