@@ -41,8 +41,16 @@ from repro.core.engine import InferenceEngine
 from repro.core.functor import TensorFunctor
 from repro.core.tensor_map import TensorMap
 from repro.obs import TRACER
+from repro.obs import metrics as _m
 from repro.obs.quality import SHADOW
 from repro.resilience.breaker import BREAKERS
+
+
+_BRIDGES = _m.counter(
+    "repro_region_bridge_total",
+    "region data bridges taken, by direction and by path: the caller's "
+    "array itself (identity) or a bridge program",
+    ("region", "direction", "path"))
 
 
 def _is_traced(*arrays):
@@ -100,8 +108,7 @@ class AsyncRegionResult:
                 raise
             BREAKERS.record_failure(region.model_path)
             return region._fallback(self._arrays, "result")
-        with TRACER.child("region.bridge_out", trace, cat="region"):
-            return region._bridge_from_jit(Y, self._arrays)
+        return region._rows_out(Y, self._arrays, trace)
 
 
 class MLRegion:
@@ -155,6 +162,17 @@ class MLRegion:
     def _bridge_from_jit(self):
         return jax.jit(self.bridge_from)
 
+    # a functor that maps the caller's array onto itself needs no program:
+    # (name, shape) of the region's one input / output whose functor is
+    # the identity at that shape, else None; decided on the first call
+    @functools.cached_property
+    def _identity_in(self):
+        return _identity(self.inputs)
+
+    @functools.cached_property
+    def _identity_out(self):
+        return _identity(self.outputs)
+
     def bridge_from(self, tensor, arrays: dict):
         """Model output tensor -> app memory (through the out functors).
 
@@ -196,12 +214,48 @@ class MLRegion:
         """Bridge app arrays to ``eng``-shaped f32 rows [n, *in_shape[1:]].
         A traced call passes its trace id and its span's ``args``."""
         in_shape = tuple(eng.spec["in_shape"])
+        ident = self._identity_in
         with TRACER.child("region.bridge_in", trace, cat="region"):
-            X = self._bridge_in_jit(arrays)
+            x = arrays[ident[0]] if ident else None
+            # a jax.Array is immutable, so the queue may park it as the
+            # rows; a numpy array is copied by the program (the caller may
+            # change it before the flush)
+            if isinstance(x, jax.Array) and x.shape == ident[1]:
+                X, path = x, "identity"
+            else:
+                X, path = self._bridge_in_jit(arrays), "program"
+            _BRIDGES.inc(region=self.name, direction="in", path=path)
             X = X.reshape((-1,) + in_shape[1:]).astype(jnp.float32)
         if args is not None:
             args["rows"] = int(X.shape[0])
         return X
+
+    def _rows_out(self, Y, arrays: dict, trace: Optional[str] = None):
+        """Bridge served rows ``Y`` to the region's outputs.  Through an
+        identity functor they are ``Y`` itself, shaped and cast as the
+        program's template; host rows (row views of the batcher's pooled
+        buffer) take one copying put to where the program would have run:
+        the device of the template when the caller committed it, else the
+        default device (a pure output's program reads no caller array)."""
+        ident = self._identity_out
+        with TRACER.child("region.bridge_out", trace, cat="region"):
+            if ident is not None:
+                name, shape = ident
+                t = arrays.get(name)  # None: a pure output
+                host = isinstance(Y, np.ndarray)
+                homes = _committed_devices(t) if host else ()
+                if (t is None or np.shape(t) == shape) and len(homes) <= 1:
+                    Y = Y.reshape(shape)
+                    if host:
+                        Y = jax.device_put(Y, next(iter(homes), None),
+                                           may_alias=False)
+                    if t is not None:
+                        Y = Y.astype(jax.dtypes.canonicalize_dtype(t.dtype))
+                    _BRIDGES.inc(region=self.name, direction="out",
+                                 path="identity")
+                    return {name: Y}
+            _BRIDGES.inc(region=self.name, direction="out", path="program")
+            return self._bridge_from_jit(Y, arrays)
 
     def _call_span(self, body, arrays: dict):
         """``body(arrays, trace, args)`` inside this eager call's
@@ -247,8 +301,7 @@ class MLRegion:
             BREAKERS.record_success(self.model_path)
         if SHADOW.enabled and not _is_traced(arrays, Xb) and SHADOW.sample():
             self._shadow_submit(arrays, rows=int(Xb.shape[0]), Y=Y)
-        with TRACER.child("region.bridge_out", trace, cat="region"):
-            return self._bridge_from_jit(Y, arrays)
+        return self._rows_out(Y, arrays, trace)
 
     def _infer_async(self, arrays: dict) -> AsyncRegionResult:
         """Enqueue this invocation on the serve queue, keyed (multiplexed)
@@ -383,6 +436,25 @@ class MLRegion:
 def _feat_dims(tm: TensorMap) -> int:
     _, feat = tm._lhs_dims()
     return len(feat)
+
+
+def _identity(side: dict):
+    """(name, shape) when ``side`` (a region's inputs or outputs) is one
+    array whose functor is the identity at ``shape``, else None."""
+    if len(side) != 1:
+        return None
+    (name, (functor, ranges)), = side.items()
+    shape = TensorMap(functor, None, ranges).identity_shape()
+    return None if shape is None else (name, shape)
+
+
+def _committed_devices(tree) -> set:
+    """The devices of the committed jax.Arrays in ``tree``: a jitted
+    bridge that reads them runs there (on the default device when none
+    is)."""
+    return {d for x in jax.tree.leaves(tree)
+            if isinstance(x, jax.Array) and x.committed
+            for d in x.devices()}
 
 
 def approx_ml(fn=None, **kw) -> MLRegion:

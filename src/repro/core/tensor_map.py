@@ -200,6 +200,30 @@ class TensorMap:
                     hi[d] = max(hi[d], end)
         return tuple(hi)
 
+    def identity_shape(self):
+        """The one array shape at which this map is the identity, or None.
+
+        At that shape ``to_tensor`` returns the array bit for bit and
+        ``from_tensor`` overwrites all of it with the tensor: one RHS slice
+        group, every offset 0, the sweep dims ahead of the feature dims,
+        each a step-1 window over its whole range, feature elements 0..F-1
+        in order, and an LHS of the same shape.
+        """
+        if len(self.descriptors) != 1:
+            return None
+        desc = self.descriptors[0]
+        shape = self.min_array_shape()
+        sweep = [s for s in desc.sweep_dims if s is not None]
+        n = len(sweep)
+        if (shape != self.tensor_shape or 0 in shape or any(desc.offsets)
+                or len(set(sweep)) != n
+                or None in desc.sweep_dims[:n]
+                or any(st != 1 for st in desc.steps[:n])):
+            return None
+        in_order = tuple(itertools.product(
+            *[range(e) if d >= n else (0,) for d, e in enumerate(shape)]))
+        return shape if desc.elem_offsets == in_order else None
+
     def __repr__(self):
         return (f"TensorMap({self.functor.name}, dir={self.direction}, "
                 f"ranges={self.ranges}, tensor_shape={self.tensor_shape})")
