@@ -121,9 +121,9 @@ def _bundle(path):
 
 
 def _prewarm(mp):
-    """Compile every bucket shape (donated + caller-owned applies) the
-    scenarios can dispatch, so the timed runs compare flush policies —
-    not which config happened to hit a fresh jit shape first."""
+    """Compile every bucket shape the scenarios can dispatch, so the
+    timed runs compare flush policies — not which config happened to hit
+    a fresh jit shape first."""
     import jax.numpy as jnp
 
     from repro.core.engine import InferenceEngine
@@ -131,8 +131,6 @@ def _prewarm(mp):
     b = 8
     while b <= 1024:
         eng.apply_batched(jnp.zeros((b, 5), np.float32))
-        eng.apply_batched(jnp.zeros((b, 5), np.float32), donate=True,
-                          prepadded=True)
         b *= 2
 
 

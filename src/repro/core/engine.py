@@ -24,30 +24,12 @@ from __future__ import annotations
 
 import os
 import threading
-import warnings
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-_donation_warning_muted = False
-
-
-def _mute_donation_warning_off_tpu():
-    """On backends without donation support (cpu) "Some donated buffers
-    were not usable" fires for every donated apply and means nothing —
-    donation there is a declared intent, not a memory saving.  On TPU
-    the warning is a real signal (an expected aliasing didn't happen),
-    so it is left alone.  Registered lazily at first donated build: the
-    backend query must not run at import time (it would initialize jax
-    before callers set XLA_FLAGS)."""
-    global _donation_warning_muted
-    if _donation_warning_muted or jax.default_backend() == "tpu":
-        return
-    warnings.filterwarnings("ignore",
-                            message="Some donated buffers were not usable")
-    _donation_warning_muted = True
-
+from repro.dist.buckets import bucket_for
 from repro.dist.sharding import constrain, current_ctx
 from repro.nn.serialize import load_model
 from repro.obs import TRACER, watch_compiles
@@ -269,7 +251,7 @@ class InferenceEngine:
         kinds = [l["kind"] for l in self.spec["layers"]]
         return all(k in ("dense", "act", "flatten") for k in kinds)
 
-    def _build(self, ctx=None, donate: bool = False):
+    def _build(self, ctx=None):
         net = self.net
         norm = bundle_norm(self.spec, net)
         mesh = ctx.mesh if ctx is not None else None
@@ -312,31 +294,20 @@ class InferenceEngine:
                 y = y * norm[3] + norm[2]
             return constrain(y, *(("data",) + (None,) * (y.ndim - 1)))
 
-        if donate:
-            _mute_donation_warning_off_tpu()
-        return jax.jit(apply_fn, donate_argnums=(1,) if donate else ())
+        return jax.jit(apply_fn)
 
-    def _apply_for(self, ctx, donate: bool = False):
+    def _apply_for(self, ctx):
         """Compiled apply for the active sharding context (traced under it,
-        so the data-axis constraints bind to that mesh).
-
-        ``donate=True`` compiles a variant that donates the batch buffer
-        to XLA (the serve path owns its padded mega-batches, so their
-        input buffers are dead after dispatch and can back the outputs).
-        Kept as a separate cache entry: a donated apply must never serve
-        a caller-owned array.
-        """
+        so the data-axis constraints bind to that mesh)."""
         # a mesh-less ctx (use_mesh(None), e.g. the batcher re-installing
         # a no-mesh submitter's context) compiles to the same program as
         # no ctx at all — share the cache entry or the serve path pays a
         # duplicate compile for every bucket shape
         key = (ctx.mesh, ctx.multi_pod) \
             if ctx is not None and ctx.mesh is not None else None
-        if donate:
-            key = (key, "donate")
         fn = self._applies.get(key)
         if fn is None:
-            fn = self._applies[key] = self._build(ctx, donate=donate)
+            fn = self._applies[key] = self._build(ctx)
         return fn
 
     def _place(self, x, ctx):
@@ -368,7 +339,7 @@ class InferenceEngine:
         return fn(self.params, self._place(x, ctx))
 
     def apply_batched(self, x, *, min_bucket: int = 8,
-                      donate: bool = False, prepadded: bool = False):
+                      prepadded: bool = False):
         """Serve a coalesced mega-batch: rows padded up to the next
         power-of-two bucket so the jit cache stays at <= log2(max batch)
         entries per context, then sliced back to the caller's row count.
@@ -376,20 +347,15 @@ class InferenceEngine:
         (and rounded to a multiple of it), so small batches never lose
         the data axis to the divisibility fallback.
 
-        ``donate=True`` asserts the caller owns ``x`` and will not touch
-        it after this call, so the compiled apply may donate its buffer
-        to XLA.  ``prepadded=True`` says ``x`` is already bucket-shaped
-        (the Batcher pads into its scratch buffer) — re-bucketing is
-        skipped; bucket rounding is not idempotent for non-power-of-two
-        shard counts, so the engine must not second-guess it.  The
-        engine also donates buffers it padded itself: the concatenated
-        copy is engine-owned by construction.
+        ``prepadded=True`` says ``x`` is already bucket-shaped (the
+        Batcher pads into its scratch buffer) — re-bucketing is skipped;
+        bucket rounding is not idempotent for non-power-of-two shard
+        counts, so the engine must not second-guess it.
 
         Row-wise nets make the padding invisible: output row i depends
         only on input row i, so callers get bit-identical rows to a
         same-input synchronous ``__call__`` (tests/test_serve.py).
         """
-        from repro.serve.batcher import bucket_for
         ctx = current_ctx()
         n = int(x.shape[0])
         if not prepadded:
@@ -399,9 +365,6 @@ class InferenceEngine:
             if b != n:
                 x = jnp.concatenate(
                     [x, jnp.zeros((b - n,) + x.shape[1:], x.dtype)], axis=0)
-                donate = True  # the padded copy is ours, not the caller's
-        if isinstance(x, jax.core.Tracer):
-            donate = False  # in-trace degrade: nothing to donate
         fault = None
         if not isinstance(x, jax.core.Tracer):
             from repro.resilience.faults import FAULTS
@@ -414,7 +377,7 @@ class InferenceEngine:
                     # (and through it the breaker's quality trip)
                     self.params = jax.tree_util.tree_map(
                         lambda p: p + fault.scale, self.params)
-        fn = self._apply_for(ctx, donate=donate)
+        fn = self._apply_for(ctx)
         x = self._place(x, ctx)
         if self.tier == "int8" and not isinstance(x, jax.core.Tracer):
             _m.counter("repro_quant_served_rows_total",

@@ -1,6 +1,11 @@
 """repro.dist: the distributed-execution substrate.
 
-Two modules:
+Three modules:
+
+``buckets``
+    The batch-bucket policy (``bucket_size``, ``bucket_for``): the
+    padded row count of every served batch, below every layer that
+    sizes one.
 
 ``sharding``
     Logical-axis sharding contexts.  Model/runtime code names *logical*

@@ -157,11 +157,9 @@ def _key(problem, backend):
 
 
 def _keys(problem, backend):
-    from repro.serve.batcher import bucket_size
-    from repro.tune.cache import shape_key
-    b = problem["batch"]
-    return [shape_key(problem["widths"], problem["dtype"], backend, bb)
-            for bb in dict.fromkeys((b, bucket_size(b)))]
+    from repro.tune.cache import batch_keys
+    return batch_keys(problem["widths"], problem["dtype"], backend,
+                      problem["batch"])
 
 
 def candidate_tiles_int8(widths, bucket, extra=()):

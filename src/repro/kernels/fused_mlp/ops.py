@@ -59,14 +59,9 @@ def _key(problem, backend):
 
 
 def _keys(problem, backend):
-    """Exact batch first (serve-path dispatches and per-shard shard_map
-    batches arrive bucket-shaped, including non-pow2 shard-rounded
-    buckets), then the power-of-two bucket covering eager calls."""
-    from repro.serve.batcher import bucket_size
-    from repro.tune.cache import shape_key
-    b = problem["batch"]
-    return [shape_key(problem["widths"], problem["dtype"], backend, bb)
-            for bb in dict.fromkeys((b, bucket_size(b)))]
+    from repro.tune.cache import batch_keys
+    return batch_keys(problem["widths"], problem["dtype"], backend,
+                      problem["batch"])
 
 
 def candidate_tiles(widths, bucket, extra=(), dtype="float32"):

@@ -9,7 +9,7 @@ admission, QoS tiers and weighted fair share on top; the residency
 manager (:mod:`repro.serve.residency`) meters loaded bundles against an
 HBM byte budget.
 """
-from repro.serve.batcher import Batcher, bucket_for, bucket_size
+from repro.serve.batcher import Batcher
 from repro.serve.queue import (Backpressure, FlushPolicy, ServeFuture,
                                ServeQueue)
 from repro.serve.residency import RESIDENCY, ResidencyManager
@@ -21,4 +21,4 @@ from repro.serve.tenancy import (DeficitRoundRobin, TenantBoard, TenantSpec,
 __all__ = ["Backpressure", "Batcher", "DeficitRoundRobin", "FlushPolicy",
            "RESIDENCY", "ResidencyManager", "ScratchPool", "ServeFuture",
            "ServeQueue", "ServeStats", "TenantBoard", "TenantSpec",
-           "TenantThrottled", "TokenBucket", "bucket_for", "bucket_size"]
+           "TenantThrottled", "TokenBucket"]

@@ -15,14 +15,17 @@ by tests/test_serve.py).
 """
 from __future__ import annotations
 
+import dataclasses
 import itertools
 import time
-from typing import Callable, List, Optional
+from typing import Callable, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.dist.buckets import bucket_for
+from repro.dist.sharding import ShardCtx, current_ctx, use_mesh
 from repro.obs import TRACER
 from repro.obs import metrics as _metrics
 from repro.resilience.breaker import BREAKERS
@@ -60,28 +63,31 @@ class NonFiniteOutput(RuntimeError):
         self.key, self.rows = key, rows
 
 
-def bucket_size(n: int, min_bucket: int = 8) -> int:
-    """Smallest power-of-two >= max(n, min_bucket).
+@dataclasses.dataclass
+class _Batch:
+    """One batch as an entry point planned it for :meth:`Batcher._serve`.
 
-    Power-of-two buckets bound the jit cache to log2(max batch) shapes
-    per sharding context.
+    ``assemble(engine)`` returns ``(X, prepadded)``; ``rows`` is the row
+    range of the applied batch to read back (None: all of it).  A local
+    batch retries ``attempts`` times and then bisects (``split``); a
+    collective gets one attempt and no split, since no host can retry or
+    bisect it alone, and across processes its failure is ``pod_fatal``.
     """
-    b = max(int(min_bucket), 1)
-    while b < n:
-        b <<= 1
-    return b
 
-
-def bucket_for(n: int, min_bucket: int, n_shards: int = 1) -> int:
-    """Dispatch bucket: power-of-two floor, rounded up to a multiple of
-    the data-shard count — a bucket smaller than (or not dividing) the
-    shard count would make `spec_for` drop the data axis and silently
-    replicate the whole batch on every device.
-    """
-    b = bucket_size(n, max(min_bucket, n_shards))
-    if n_shards > 1 and b % n_shards:
-        b += -b % n_shards
-    return b
+    key: str
+    requests: List
+    stats: ServeStats
+    reason: str
+    bid: int
+    t0: float
+    bucket: int
+    ctx: Optional[ShardCtx]
+    assemble: Callable[..., Tuple[object, bool]]
+    attempts: int
+    split: bool = True
+    pod_fatal: bool = False
+    rows: Optional[Tuple[int, int]] = None
+    remote_rows: int = 0
 
 
 class Batcher:
@@ -131,11 +137,11 @@ class Batcher:
     def _gather(self, requests, n: int, bucket: int):
         """Assemble the mega-batch.
 
-        A lone request rides through untouched (the engine pads it).
-        Device-resident inputs concatenate on device — no D2H round-trip;
-        the concat output is batcher-owned and safe to donate.  Host
-        inputs gather into a pooled scratch buffer already padded to the
-        bucket, so the engine skips its own concat+pad.
+        Returns ``(X, prepadded)``.  A lone request rides through
+        untouched (the engine pads it).  Device-resident inputs
+        concatenate on device — no D2H round-trip.  Host inputs gather
+        into a pooled scratch buffer already padded to the bucket, so the
+        engine skips its own concat+pad.
         """
         if len(requests) == 1:
             return requests[0].x, False
@@ -146,14 +152,18 @@ class Batcher:
                 parts.append(jnp.zeros((bucket - n,) + feat,
                                        requests[0].x.dtype))
             return jnp.concatenate(parts, axis=0), True
-        buf = self.scratch.take((bucket,) + feat,
-                                np.dtype(requests[0].x.dtype))
+        return jnp.asarray(self._stage(requests, bucket, feat,
+                                       requests[0].x.dtype)), True
+
+    def _stage(self, requests, rows: int, feat, dtype) -> np.ndarray:
+        """The requests' rows in a pooled host buffer of ``rows`` rows."""
+        buf = self.scratch.take((rows,) + feat, np.dtype(dtype))
         off = 0
         for r in requests:
             buf[off:off + r.n] = np.asarray(r.x)
             off += r.n
         buf[off:] = 0  # zero padding: same rows a jnp pad would produce
-        return jnp.asarray(buf), True
+        return buf
 
     def _to_host(self, Y, *, rows=None) -> np.ndarray:
         """One device->host gather of rows ``[rows[0], rows[1])`` (default:
@@ -202,36 +212,21 @@ class Batcher:
                 f"gather the array before landing it.")
         return out
 
-    @staticmethod
-    def _request_ctx(requests):
-        """Install the submitters' ShardCtx around the batched apply.
-
-        Sharding contexts are thread-local; a deadline/max-batch flush
-        runs on the dispatcher thread, which would otherwise serve the
-        mega-batch unsharded.  The submit-time ctx governs even when it
-        is None (a no-mesh submit flushed inline from inside someone
-        else's ``use_mesh`` must not pick up that ambient mesh, or the
-        engine's bucket would diverge from the one stats recorded).
-        Requests queued under different meshes never coalesce
-        meaningfully, so the first request's ctx speaks for the batch
-        (they arrived FIFO on one key).
-        """
-        from repro.dist.sharding import use_mesh
-        ctx = requests[0].ctx
-        if ctx is None:
-            return use_mesh(None)
-        return use_mesh(ctx.mesh, ctx.multi_pod)
-
-    def _fail_all(self, requests, exc, stats, reason, busy_s, *,
-                  record_breaker_key=None):
-        for r in requests:
+    def _fail_all(self, b: _Batch, exc: Exception) -> None:
+        for r in b.requests:
             r.future.set_exception(exc)
-        self._note_dropped(requests)
-        stats.on_failure(requests=len(requests),
-                         rows=sum(r.n for r in requests), reason=reason,
-                         busy_s=busy_s)
-        if record_breaker_key is not None:
-            BREAKERS.record_failure(record_breaker_key)
+        self._note_dropped(b.requests)
+        b.stats.on_failure(requests=len(b.requests),
+                           rows=sum(r.n for r in b.requests),
+                           reason=b.reason, busy_s=time.monotonic() - b.t0)
+        BREAKERS.record_failure(b.key)
+        if b.pod_fatal:
+            # a host that bails after the count all-gather (bundle read
+            # failure, bad dtype, ...) has already diverged from the
+            # collective schedule its peers are entering — swallowing the
+            # error here would leave them hung in the apply.  Fail loudly
+            # so whatever launched the pod tears it down.
+            raise exc
 
     # ------------------------------------------------ tenant attribution ---
     def _note_dropped(self, requests) -> None:
@@ -299,8 +294,8 @@ class Batcher:
         1. Engine *load* failures (missing/corrupt bundle) are
            deterministic — fail the whole batch once, no retry, no split.
         2. Compute/landing failures retry up to ``retry.max_attempts``
-           with capped exponential backoff (the mega-batch is re-gathered
-           each attempt — a donated buffer is dead after a failed apply).
+           with capped exponential backoff (the assembled mega-batch is
+           reused; only a failed assembly is redone).
         3. A multi-request batch that exhausts its retries is bisected
            (split-retry): each half re-dispatches with a single attempt,
            recursing down to singles, so one poisoned request cannot fail
@@ -309,16 +304,32 @@ class Batcher:
            converted to per-request :class:`NonFiniteOutput` failures,
            never silently returned.
 
-        Every outcome feeds the per-key circuit breaker.
+        Every outcome feeds the per-key circuit breaker.  The first
+        request's submit-time ShardCtx serves the batch (requests arrive
+        FIFO on one key; different meshes never coalesce meaningfully).
         """
         if not requests:
             return
         # monotonic throughout: latencies subtract submit-time stamps
         # taken with time.monotonic(), and mixing clocks is undefined
         t0 = time.monotonic()
+        n = sum(r.n for r in requests)
+        ctx = requests[0].ctx
+        shards = (ctx.axis_size("data")
+                  if ctx is not None and ctx.mesh is not None else 1)
+        bucket = bucket_for(n, self.min_bucket, shards)
+        self._serve(_Batch(
+            key, requests, stats, reason, next(_BATCH_IDS), t0, bucket, ctx,
+            lambda eng: self._gather(requests, n, bucket),
+            attempts=(self.retry.max_attempts if _attempts is None
+                      else max(1, _attempts))))
+
+    def _serve(self, b: _Batch) -> None:
+        """Assemble, apply, land, screen, scatter and account one batch:
+        the one routine behind :meth:`dispatch` and :meth:`dispatch_pod`."""
         tr = TRACER
         traced = tr.enabled
-        bid = next(_BATCH_IDS)
+        key, requests, bid = b.key, b.requests, b.bid
         try:
             eng = self._engine_for(key)
         except Exception as e:
@@ -326,32 +337,31 @@ class Batcher:
             # splitting would re-fail identically request by request
             tr.instant("batch.error", cat="batch",
                        args={"key": key, "batch": bid, "error": repr(e)})
-            self._fail_all(requests, e, stats, reason,
-                           time.monotonic() - t0, record_breaker_key=key)
+            self._fail_all(b, e)
             return
         n = sum(r.n for r in requests)
-        ctx = requests[0].ctx
-        shards = (ctx.axis_size("data")
-                  if ctx is not None and ctx.mesh is not None else 1)
-        bucket = bucket_for(n, self.min_bucket, shards)
-        attempts = self.retry.max_attempts if _attempts is None \
-            else max(1, _attempts)
-        Y = None
+        X = Y = None
         last_exc: Optional[Exception] = None
-        for attempt in range(attempts):
+        for attempt in range(b.attempts):
             try:
-                with tr.span("batch.gather", cat="batch",
-                             args={"key": key, "batch": bid, "rows": n,
-                                   "bucket": bucket,
-                                   "requests": len(requests)}):
-                    X, owned = self._gather(requests, n, bucket)
+                if X is None:
+                    with tr.span("batch.gather", cat="batch",
+                                 args={"key": key, "batch": bid, "rows": n,
+                                       "bucket": b.bucket,
+                                       "requests": len(requests)}):
+                        X, prepadded = b.assemble(eng)
                 with tr.span("batch.apply", cat="batch",
                              args={"key": key, "batch": bid,
-                                   "bucket": bucket, "reason": reason,
+                                   "bucket": b.bucket, "reason": b.reason,
                                    "attempt": attempt}):
-                    with self._request_ctx(requests):
+                    # sharding contexts are thread-local: the planned ctx
+                    # governs even when None, so a flush on the dispatcher
+                    # thread or inside someone else's use_mesh applies
+                    # with the mesh the bucket was sized for
+                    with (use_mesh(None) if b.ctx is None
+                          else use_mesh(b.ctx.mesh, b.ctx.multi_pod)):
                         Y = eng.apply_batched(X, min_bucket=self.min_bucket,
-                                              donate=owned, prepadded=owned)
+                                              prepadded=prepadded)
                     Y = jax.block_until_ready(Y)
                 # one device->host gather for the whole mega-batch:
                 # scattering zero-copy numpy row views is ~1000x cheaper
@@ -359,18 +369,18 @@ class Batcher:
                 # such slice is a cross-device gather of its own)
                 with tr.span("batch.to_host", cat="batch",
                              args={"key": key, "batch": bid}):
-                    Y = self._to_host(Y)
+                    Y = self._to_host(Y, rows=b.rows)
                 break
             except Exception as e:
                 Y, last_exc = None, e
                 tr.instant("batch.error", cat="batch",
                            args={"key": key, "batch": bid,
                                  "attempt": attempt, "error": repr(e)})
-                if attempt + 1 < attempts:
+                if attempt + 1 < b.attempts:
                     _RETRIES.inc(1, key=key)
                     time.sleep(self.retry.delay_for(attempt))
         if Y is None:
-            if len(requests) > 1:
+            if b.split and len(requests) > 1:
                 # split-retry: bisect so a poisoned request fails alone;
                 # children get one attempt each (the backoff budget was
                 # already spent above) and recurse down to singles
@@ -379,15 +389,14 @@ class Batcher:
                            args={"key": key, "batch": bid,
                                  "requests": len(requests)})
                 mid = len(requests) // 2
-                self.dispatch(key, requests[:mid], stats, reason,
+                self.dispatch(key, requests[:mid], b.stats, b.reason,
                               _attempts=1)
-                self.dispatch(key, requests[mid:], stats, reason,
+                self.dispatch(key, requests[mid:], b.stats, b.reason,
                               _attempts=1)
                 return
-            self._fail_all(requests, last_exc, stats, reason,
-                           time.monotonic() - t0, record_breaker_key=key)
+            self._fail_all(b, last_exc)
             return
-        if FAULTS.enabled:
+        if FAULTS.enabled and requests:
             rule = FAULTS.fire("batcher.scatter", key=key)
             if rule is not None and rule.mode in ("nan", "inf"):
                 Y = np.array(Y)  # writable copy on the injected path only
@@ -404,7 +413,7 @@ class Batcher:
         # request of the batch (rec() documents shared-args safety).
         rargs = sargs = None
         if traced:
-            rargs = {"key": key, "batch": bid, "reason": reason}
+            rargs = {"key": key, "batch": bid, "reason": b.reason}
             sargs = {"key": key, "batch": bid, "requests": len(requests)}
         with tr.span("batch.scatter", cat="batch", args=sargs):
             for i, r in enumerate(requests):
@@ -425,15 +434,17 @@ class Batcher:
             tr.instant("batch.nonfinite", cat="batch",
                        args={"key": key, "batch": bid,
                              "requests": len(bad), "rows": bad_rows})
-            stats.on_failure(requests=len(bad), rows=bad_rows,
-                             reason=reason, busy_s=0.0)
+            b.stats.on_failure(requests=len(bad), rows=bad_rows,
+                               reason=b.reason, busy_s=0.0)
             BREAKERS.record_failure(key)
         else:
             BREAKERS.record_success(key)
-        if len(bad) < len(requests):
-            stats.on_batch(requests=len(requests) - len(bad),
-                           rows=n - bad_rows, bucket=bucket, reason=reason,
-                           busy_s=t1 - t0, latencies_s=lats)
+        # an idle pod host still accounts the collective it joined
+        if not requests or len(bad) < len(requests):
+            b.stats.on_batch(requests=len(requests) - len(bad),
+                             rows=n - bad_rows, bucket=b.bucket,
+                             reason=b.reason, busy_s=t1 - b.t0,
+                             latencies_s=lats, remote_rows=b.remote_rows)
             # drift re-sweep trigger: a sustained bucket with no tune
             # entry enqueues a background sweep of that exact cell.
             # Lazy import + disabled fast path keep this a no-op unless
@@ -441,7 +452,7 @@ class Batcher:
             from repro.tune.resweep import get_resweeper
             rs = get_resweeper()
             if rs.enabled:
-                rs.observe(eng, bucket, stats)
+                rs.observe(eng, b.bucket, b.stats)
 
     @staticmethod
     def _dtype_from_num(num: int):
@@ -467,21 +478,6 @@ class Batcher:
         raise ValueError(f"dispatch_pod: unknown dtype num {num} gathered "
                          f"from a pod peer")
 
-    def _slab_layout(self, requests, eng, agreed_num: int = -1):
-        """(feature shape, dtype) of one slab row.
-
-        From the local requests when this host has any; an idle host
-        derives the feature shape from the engine's bundle spec and the
-        dtype from the pod-agreed type number — every process must hand
-        ``make_array_from_process_local_data`` the same dtype or the
-        global array's avals diverge across the pod."""
-        if requests:
-            return (tuple(requests[0].x.shape[1:]),
-                    np.dtype(requests[0].x.dtype))
-        dtype = (self._dtype_from_num(agreed_num) if agreed_num >= 0
-                 else np.dtype(np.float32))
-        return tuple(eng.spec["in_shape"][1:]), dtype
-
     def dispatch_pod(self, key: str, requests: List, stats: ServeStats, *,
                      ctx=None, reason: str = "pod") -> None:
         """Serve one cross-host mega-batch (collective).
@@ -501,11 +497,8 @@ class Batcher:
         overrides the serving ShardCtx for exactly that case — with no
         local requests there is no submit-time ctx to recover.
         """
-        from repro.dist.sharding import current_ctx, use_mesh
         from repro.launch import multihost
         t0 = time.monotonic()
-        tr = TRACER
-        traced = tr.enabled
         bid = next(_BATCH_IDS)
         if ctx is None:
             ctx = requests[0].ctx if requests else current_ctx()
@@ -513,108 +506,47 @@ class Batcher:
         my_num = int(np.dtype(requests[0].x.dtype).num) if requests else -1
         # pod.agree: the count/dtype all-gather is where a straggling
         # host shows up — every peer's span stretches to the slowest one
-        with tr.span("pod.agree", cat="pod",
-                     args={"key": key, "batch": bid, "local_rows": local_n}):
+        with TRACER.span("pod.agree", cat="pod",
+                         args={"key": key, "batch": bid,
+                               "local_rows": local_n}):
             gathered = multihost.allgather_ints([local_n, my_num])
         counts, dtype_nums = gathered[:, 0], gathered[:, 1]
         total = int(counts.sum())
         if total == 0:
             return
         pid, nproc = multihost.process_index(), len(counts)
-        try:
-            if nproc > 1 and (ctx is None or ctx.mesh is None):
+        meshed = ctx is not None and ctx.mesh is not None
+        per_slab = bucket_for(int(counts.max()), self.min_bucket,
+                              ctx.local_axis_size("data") if meshed else 1)
+        bucket = per_slab * nproc
+
+        def assemble(eng):
+            if nproc > 1 and not meshed:
                 raise RuntimeError(
                     "dispatch_pod: cross-process serving needs a pod mesh "
                     "— submit under use_mesh(make_pod_mesh(), "
                     "multi_pod=True) or pass ctx=")
-            # hosts with rows must agree on the row dtype; idle hosts
-            # adopt it so every process assembles the same global aval
+            # hosts with rows must agree on the row dtype; an idle host
+            # adopts it and takes its feature shape from the bundle spec,
+            # or the global array's avals diverge across the pod
             active = {int(c) for c, k in zip(dtype_nums, counts) if k > 0}
             if len(active) > 1:
                 raise ValueError(
                     f"dispatch_pod: pod hosts submitted mixed row dtypes "
                     f"for {key!r} (type nums {sorted(active)})")
-            eng = self._engine_for(key)
-            feat, dtype = self._slab_layout(requests, eng,
-                                            next(iter(active), -1))
-            local_shards = (ctx.local_axis_size("data")
-                            if ctx is not None and ctx.mesh is not None
-                            else 1)
-            per_slab = bucket_for(int(counts.max()), self.min_bucket,
-                                  local_shards)
-            bucket = per_slab * nproc
-            slab = self.scratch.take((per_slab,) + feat, dtype)
-            off = 0
-            for r in requests:
-                slab[off:off + r.n] = np.asarray(r.x)
-                off += r.n
-            slab[off:] = 0
-            if ctx is not None and ctx.mesh is not None:
-                X = ctx.make_global(slab, ("data",) + (None,) * len(feat),
-                                    global_shape=(bucket,) + feat)
-            else:
-                X = jnp.asarray(slab)
-            with tr.span("batch.apply", cat="pod",
-                         args={"key": key, "batch": bid, "bucket": bucket,
-                               "pid": pid, "nproc": nproc,
-                               "local_rows": local_n, "total_rows": total}):
-                with (use_mesh(ctx.mesh, ctx.multi_pod) if ctx is not None
-                      else use_mesh(None)):
-                    Y = eng.apply_batched(X, min_bucket=self.min_bucket,
-                                          prepadded=True)
-                Y = jax.block_until_ready(Y)
             if requests:
-                base = pid * per_slab
-                with tr.span("batch.to_host", cat="pod",
-                             args={"key": key, "batch": bid}):
-                    Yh = self._to_host(Y, rows=(base, base + local_n))
-        except Exception as e:
-            tr.instant("batch.error", cat="pod",
-                       args={"key": key, "batch": bid, "error": repr(e)})
-            for r in requests:
-                r.future.set_exception(e)
-            self._note_dropped(requests)
-            stats.on_failure(requests=len(requests), rows=local_n,
-                             reason=reason, busy_s=time.monotonic() - t0)
-            BREAKERS.record_failure(key)
-            if nproc > 1:
-                # pod-fatal: a host that bails after the count all-gather
-                # (bundle read failure, bad dtype, ...) has already
-                # diverged from the collective schedule its peers are
-                # entering — swallowing the error here would leave them
-                # hung in the apply.  Fail loudly so the driver/harness
-                # tears the pod down.
-                raise
-            return
-        bad = self._screen_nonfinite(requests, Yh) if requests else ()
-        t1 = time.monotonic()
-        off = 0
-        lats = []
-        bad_rows = 0
-        rargs = {"key": key, "batch": bid, "reason": reason,
-                 "pid": pid, "nproc": nproc} if traced else None
-        for i, r in enumerate(requests):
-            if i in bad:
-                r.future.set_exception(NonFiniteOutput(key, r.n))
-                bad_rows += r.n
-                off += r.n
-                continue
-            r.future.set_result(Yh[off:off + r.n])
-            off += r.n
-            lats.append(t1 - r.t_enqueue)
-            if traced:
-                tr.rec("serve.request", "serve", r.t_enqueue,
-                       time.monotonic(), r.trace, rargs)
-        self._note_served(requests, bad, lats)
-        if bad:
-            _NONFINITE.inc(bad_rows, key=key)
-            stats.on_failure(requests=len(bad), rows=bad_rows,
-                             reason=reason, busy_s=0.0)
-            BREAKERS.record_failure(key)
-        else:
-            BREAKERS.record_success(key)
-        if not requests or len(bad) < len(requests):
-            stats.on_batch(requests=len(requests) - len(bad),
-                           rows=local_n - bad_rows, bucket=bucket,
-                           reason=reason, busy_s=t1 - t0, latencies_s=lats,
-                           remote_rows=total - local_n)
+                feat, dtype = requests[0].x.shape[1:], requests[0].x.dtype
+            else:
+                feat = tuple(eng.spec["in_shape"][1:])
+                dtype = self._dtype_from_num(active.pop())
+            slab = self._stage(requests, per_slab, feat, dtype)
+            if meshed:
+                return ctx.make_global(slab, ("data",) + (None,) * len(feat),
+                                       global_shape=(bucket,) + feat), True
+            return jnp.asarray(slab), True
+
+        base = pid * per_slab
+        self._serve(_Batch(
+            key, requests, stats, reason, bid, t0, bucket, ctx, assemble,
+            attempts=1, split=False, pod_fatal=nproc > 1,
+            rows=(base, base + local_n), remote_rows=total - local_n))

@@ -151,24 +151,53 @@ class _StatsGate:
         self._consumed = False
 
     def on_batch(self, **kw) -> None:
-        with self._lock:
-            if self._dead:
-                return
-            self._consumed = True
-        self._stats.on_batch(**kw)
+        self._deliver(self._stats.on_batch, kw)
 
     def on_failure(self, **kw) -> None:
+        self._deliver(self._stats.on_failure, kw)
+
+    def _deliver(self, record, kw) -> None:
         with self._lock:
             if self._dead:
                 return
             self._consumed = True
-        self._stats.on_failure(**kw)
+        record(**kw)
 
     def kill(self) -> bool:
         """Revoke the gate; True when nothing was delivered through it."""
         with self._lock:
             self._dead = True
             return not self._consumed
+
+    def __getattr__(self, name):
+        # reads (the re-sweep's bucket_batches) pass through
+        return getattr(self._stats, name)
+
+
+def _under_watchdog(fn, name: str):
+    """Run the collective ``fn`` on a daemon thread under the pod
+    watchdog (``REPRO_POD_WATCHDOG_S``): ``(True, result)`` when it
+    finished in time — its exception re-raised, a failed collective is
+    pod-fatal — and ``(False, None)`` when it stalled past the watchdog,
+    left behind as a zombie daemon thread."""
+    from repro.launch import multihost
+    box: Dict[str, object] = {}
+    done = threading.Event()
+
+    def run():
+        try:
+            box["out"] = fn()
+        except BaseException as e:
+            box["exc"] = e
+        finally:
+            done.set()
+
+    threading.Thread(target=run, daemon=True, name=name).start()
+    if not done.wait(timeout=multihost.pod_watchdog_s()):
+        return False, None
+    if "exc" in box:
+        raise box["exc"]
+    return True, box.get("out")
 
 
 class ServeQueue:
@@ -422,18 +451,22 @@ class ServeQueue:
         dispatched = 0
         keys = [key] if key is not None else self._flush_order()
         for k in keys:
-            with self._cv:
-                reqs = self._pending.pop(k, [])
-                rows = sum(r.n for r in reqs)
-                self._rows_total -= rows
-                st = self._stat_locked(k)
-                if rows:
-                    self._cv.notify_all()  # wake backpressured submitters
+            reqs, rows, st = self._take(k)
             if reqs:
                 self._note_dispatch(reqs)
                 self._batcher.dispatch(k, reqs, st, reason)
                 dispatched += rows
         return dispatched
+
+    def _take(self, key: str):
+        """Pop ``key``'s pending requests: (requests, rows, stats)."""
+        with self._cv:
+            reqs = self._pending.pop(key, [])
+            rows = sum(r.n for r in reqs)
+            self._rows_total -= rows
+            if rows:
+                self._cv.notify_all()  # wake backpressured submitters
+            return reqs, rows, self._stat_locked(key)
 
     def _flush_order(self) -> List[str]:
         """Key order for an all-keys flush: FIFO insertion order, unless
@@ -520,13 +553,7 @@ class ServeQueue:
             keys = self._agree_pod_keys(keys)
         dispatched = 0
         for k in keys:
-            with self._cv:
-                reqs = self._pending.pop(k, [])
-                rows = sum(r.n for r in reqs)
-                self._rows_total -= rows
-                st = self._stat_locked(k)
-                if rows:
-                    self._cv.notify_all()  # wake backpressured submitters
+            reqs, rows, st = self._take(k)
             self._note_dispatch(reqs)
             if not multi:
                 # single process: the collective is trivially local and
@@ -559,27 +586,13 @@ class ServeQueue:
         from repro.launch import multihost
         health = multihost.POD_HEALTH
         round_id = health.beat()
-        box: Dict[str, object] = {}
-        done = threading.Event()
-
-        def run():
-            try:
-                box["got"] = multihost.allgather_bytes(
-                    json.dumps(sorted(local)).encode())
-            except BaseException as e:
-                box["exc"] = e
-            finally:
-                done.set()
-
-        t = threading.Thread(target=run, daemon=True,
-                             name="repro-pod-key-agree")
-        t.start()
-        if done.wait(timeout=multihost.pod_watchdog_s()):
-            exc = box.get("exc")
-            if exc is not None:
-                raise exc  # transport failure is pod-fatal, same as dispatch
+        finished, got = _under_watchdog(
+            lambda: multihost.allgather_bytes(
+                json.dumps(sorted(local)).encode()),
+            "repro-pod-key-agree")
+        if finished:
             agreed = set()
-            for blob in box["got"]:
+            for blob in got:
                 agreed.update(json.loads(bytes(blob).decode()))
             return sorted(agreed)
         offenders = health.check_round(round_id)
@@ -595,28 +608,12 @@ class ServeQueue:
         health = multihost.POD_HEALTH
         round_id = health.beat()
         gate = _StatsGate(st)
-        box: Dict[str, BaseException] = {}
-        done = threading.Event()
-
-        def run():
-            try:
-                self._batcher.dispatch_pod(k, reqs, gate, ctx=ctx)
-            except BaseException as e:
-                box["exc"] = e
-            finally:
-                done.set()
-
-        t = threading.Thread(target=run, daemon=True,
-                             name="repro-pod-dispatch")
-        t.start()
-        if done.wait(timeout=multihost.pod_watchdog_s()):
-            exc = box.get("exc")
-            if exc is not None:
-                raise exc  # pod-fatal contract preserved
-            return
-        # watchdog fired.  kill() returning False means the collective
+        finished, _ = _under_watchdog(
+            lambda: self._batcher.dispatch_pod(k, reqs, gate, ctx=ctx),
+            "repro-pod-dispatch")
+        # watchdog fired?  kill() returning False means the collective
         # delivered in the race window between timeout and now — take it.
-        if not gate.kill():
+        if finished or not gate.kill():
             return
         offenders = health.check_round(round_id)
         health.mark_degraded(offenders)
@@ -711,38 +708,29 @@ class ServeQueue:
 
     def _on_dispatcher_crash(self, exc: BaseException) -> None:
         with self._cv:
-            self._crashed = exc
-            pending, self._pending = self._pending, {}
-            self._rows_total = 0
-            stats = {k: self._stat_locked(k) for k in pending}
-            self._cv.notify_all()  # unblock backpressured submitters
+            self._crashed = exc  # from here on submits refuse
         err = RuntimeError(f"serve dispatcher thread died: {exc!r}")
         err.__cause__ = exc
         TRACER.instant("queue.crash", cat="queue",
                        args={"error": repr(exc)})
+        self._fail_pending(err, "dispatcher_crash")
+
+    def _fail_pending(self, err: BaseException, reason: str) -> None:
+        """Fail every pending request with ``err``, without a dispatch
+        (dispatcher crash, drain-free close)."""
+        with self._cv:
+            pending, self._pending = self._pending, {}
+            self._rows_total = 0
+            stats = {k: self._stat_locked(k) for k in pending}
+            self._cv.notify_all()  # unblock backpressured submitters
         for k, reqs in pending.items():
-            self._note_failed(reqs)
+            self._note_dispatch(reqs)
+            self._batcher._note_dropped(reqs)
             for r in reqs:
                 r.future.set_exception(err)
             stats[k].on_failure(requests=len(reqs),
                                 rows=sum(r.n for r in reqs),
-                                reason="dispatcher_crash", busy_s=0.0)
-
-    def _note_failed(self, reqs: List) -> None:
-        """Tenant accounting for requests failed without a dispatch
-        (dispatcher crash, drain-free close)."""
-        self._note_dispatch(reqs)
-        if self.tenancy is None:
-            return
-        agg: Dict[str, list] = {}
-        for r in reqs:
-            t = getattr(r, "tenant", None)
-            if t is not None:
-                c = agg.setdefault(t, [0, 0])
-                c[0] += 1
-                c[1] += r.n
-        for t, (n_req, n_rows) in agg.items():
-            self.tenancy.on_dropped(t, n_req, n_rows)
+                                reason=reason, busy_s=0.0)
 
     # ------------------------------------------------------------ close ---
     def close(self, drain: bool = True, *, timeout: float = 30.0) -> None:
@@ -763,19 +751,8 @@ class ServeQueue:
         elif drain:
             self.flush(reason="close")
         if not drain:
-            with self._cv:
-                pending, self._pending = self._pending, {}
-                self._rows_total = 0
-                stats = {k: self._stat_locked(k) for k in pending}
-                self._cv.notify_all()
-            err = RuntimeError("ServeQueue closed before dispatch")
-            for k, reqs in pending.items():
-                self._note_failed(reqs)
-                for r in reqs:
-                    r.future.set_exception(err)
-                stats[k].on_failure(requests=len(reqs),
-                                    rows=sum(r.n for r in reqs),
-                                    reason="close", busy_s=0.0)
+            self._fail_pending(
+                RuntimeError("ServeQueue closed before dispatch"), "close")
         from repro.obs.quality import SHADOW
         SHADOW.close(drain=drain, timeout=timeout)
 

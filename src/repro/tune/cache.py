@@ -36,9 +36,11 @@ import os
 import pathlib
 import tempfile
 import threading
-from typing import Dict, Iterable, Optional, Sequence
+from typing import Dict, Iterable, List, Optional, Sequence
 
 import numpy as np
+
+from repro.dist.buckets import bucket_size
 
 ART = pathlib.Path(__file__).resolve().parents[3] / "artifacts" / "tune"
 
@@ -61,6 +63,18 @@ def shape_key(widths: Iterable[int], dtype, backend: str, bucket: int) -> str:
     format so legacy entries keep hitting after migration)."""
     w = "-".join(str(int(v)) for v in widths)
     return f"{w}|{_dtype_name(dtype)}|{backend}|b{int(bucket)}"
+
+
+def batch_keys(widths: Iterable[int], dtype, backend: str,
+               batch: int) -> List[str]:
+    """A fused_mlp call's lookup chain: the exact batch first — serve-path
+    dispatches (and the per-shard batches inside ``fused_mlp_sharded``)
+    arrive already bucket-shaped, including the non-power-of-two buckets
+    a shard-count rounding produces — then the power-of-two bucket, which
+    covers eager calls of arbitrary size."""
+    batch = int(batch)
+    return [shape_key(widths, dtype, backend, b)
+            for b in dict.fromkeys((batch, bucket_size(batch)))]
 
 
 def _migrate_record(rec: dict) -> dict:
@@ -237,19 +251,10 @@ def best_params(kernel: str, keys: Sequence[str]) -> Optional[Dict[str, int]]:
 
 
 def best_tile(widths, dtype, backend: str, batch: int) -> Optional[int]:
-    """Tuned ``batch_tile`` for a fused_mlp call, or None when untuned.
-
-    The exact batch is tried first — serve-path dispatches (and the
-    per-shard batches inside ``fused_mlp_sharded``) arrive already
-    bucket-shaped, including the non-power-of-two buckets a shard-count
-    rounding produces — then the power-of-two bucket, which covers
-    eager calls of arbitrary size.
-    """
-    from repro.serve.batcher import bucket_size
-    batch = int(batch)
-    keys = [shape_key(widths, dtype, backend, b)
-            for b in dict.fromkeys((batch, bucket_size(batch)))]
-    params = best_params("fused_mlp", keys)
+    """Tuned ``batch_tile`` for a fused_mlp call (looked up along
+    :func:`batch_keys`), or None when untuned."""
+    params = best_params("fused_mlp", batch_keys(widths, dtype, backend,
+                                                 batch))
     if params is None:
         return None
     tile = params.get("batch_tile")

@@ -67,6 +67,7 @@ import threading
 import time
 from typing import Callable, Dict, Optional, Tuple
 
+from repro.dist.buckets import bucket_size
 from repro.dist.hlo_analysis import HBM_BW, PEAK_FLOPS, Roofline
 from repro.obs import metrics as _m
 
@@ -247,7 +248,6 @@ class AdaptiveFlushController:
             memo = self._target_memo.get(key)
             if memo is not None and now - memo[0] < self.decision_ttl_s:
                 return memo[1]
-        from repro.serve.batcher import bucket_size
         lo = bucket_size(1, self.policy.min_bucket)
         hi = bucket_size(self.policy.max_batch_rows, self.policy.min_bucket)
         asymptote = self.latency_s(widths, hi, stats)[0] / hi
@@ -293,7 +293,6 @@ class AdaptiveFlushController:
         # the service cap prices the batch *already pending* (waiting
         # longer than it costs to serve what is queued buys nothing —
         # more rows may never come), not the aspirational target bucket
-        from repro.serve.batcher import bucket_size
         pending = max(int(getattr(stats, "queue_depth_rows", 0) or 0), 1)
         cap_bucket = bucket_size(pending, self.policy.min_bucket)
         if self.use_measured and stats is not None:

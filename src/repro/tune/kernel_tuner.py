@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.dist.buckets import bucket_for
 from repro.kernels import registry
 from repro.kernels.fused_mlp.ops import DEFAULT_TILE, candidate_tiles
 from repro.tune.cache import TuneCache, _dtype_name, default_cache
@@ -193,7 +194,6 @@ def serve_buckets(min_bucket: int = 8, max_batch_rows: int = 1024,
     """The batch buckets ``apply_batched`` can actually dispatch for a
     flush policy: powers of two from the (shard-raised) floor up to the
     bucket covering max_batch_rows."""
-    from repro.serve.batcher import bucket_for
     lo = bucket_for(1, min_bucket, n_shards)
     hi = bucket_for(max_batch_rows, min_bucket, n_shards)
     out, b = [], lo

@@ -392,10 +392,9 @@ def test_batched_apply_module_is_named_jit_apply_fn(tmp_path):
     from repro.dist.sharding import current_ctx
     eng = InferenceEngine.get(_bundle(tmp_path, seed=4))
     x = jax.device_put(_rows(8))
-    for donate in (False, True):
-        fn = eng._apply_for(current_ctx(), donate=donate)
-        hlo = fn.lower(eng.params, x).compile().as_text()
-        assert hlo.startswith("HloModule jit_apply_fn"), hlo[:80]
+    fn = eng._apply_for(current_ctx())
+    hlo = fn.lower(eng.params, x).compile().as_text()
+    assert hlo.startswith("HloModule jit_apply_fn"), hlo[:80]
 
 
 def test_pod_flush_single_process_traced(tmp_path):

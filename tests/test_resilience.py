@@ -227,14 +227,21 @@ def test_engine_load_failure_fails_batch_once_no_retry():
     assert q.stats("k").snapshot()["batches_failed"] == 1
 
 
-def test_nonfinite_screening_isolates_poisoned_request():
+# the batcher's two entry points share one batch routine: a local flush
+# and a single-process collective one
+ROUTES = {"flush": lambda q, key: q.flush(key),
+          "pod_flush": lambda q, key: q.pod_flush(key)}
+
+
+@pytest.mark.parametrize("route", sorted(ROUTES))
+def test_nonfinite_screening_isolates_poisoned_request(route):
     eng = _StubEngine()
     q = _queue(eng)
     FAULTS.configure("batcher.scatter:nan:n=1")
     xa, xb = _rows(3, seed=4), _rows(2, seed=5)
     fa = q.submit("k", xa)
     fb = q.submit("k", xb)
-    q.flush("k")
+    ROUTES[route](q, "k")
     # the injected NaN lands on the first request's rows only
     with pytest.raises(NonFiniteOutput):
         fa.result(5)

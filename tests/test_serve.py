@@ -1,7 +1,9 @@
 """repro.serve: queue/batcher flush policies, bucket padding round-trip,
 multiplexed regions, deadline determinism, stats, backpressure — plus the
 engine's bucketed apply + sharding-resolution cache it rides on."""
+import ast
 import os
+import pathlib
 import time
 
 import jax
@@ -12,12 +14,13 @@ import pytest
 from repro.apps import binomial, miniweather
 from repro.core import approx_ml, tensor_functor
 from repro.core.engine import InferenceEngine
+from repro.dist.buckets import bucket_for, bucket_size
 from repro.dist.sharding import ShardCtx, use_mesh
 from repro.launch.mesh import make_local_mesh
 from repro.nn import MLP
 from repro.nn.layers import Activation, Conv2D, Sequential
 from repro.nn.serialize import save_model
-from repro.serve import (Backpressure, FlushPolicy, ServeQueue, bucket_size)
+from repro.serve import Backpressure, FlushPolicy, ServeQueue
 
 _ifn = tensor_functor("sin: [i, 0:2] = ([i, 0:2])")
 _ofn = tensor_functor("sout: [i, 0:1] = ([i, 0:1])")
@@ -54,7 +57,6 @@ def test_bucket_size_pow2_and_min():
 
 
 def test_bucket_for_respects_data_shard_count():
-    from repro.serve import bucket_for
     # no mesh: plain power-of-two behavior
     assert bucket_for(6, 8, 1) == 8
     # 16 data shards: a small batch must not shrink below the shard
@@ -68,7 +70,6 @@ def test_bucket_for_respects_data_shard_count():
 
 
 def test_bucket_size_edges():
-    from repro.serve import bucket_for
     # n=0: the floor governs (a zero-row dispatch never happens, but the
     # controller's target math must not blow up on it)
     assert bucket_size(0) == 8
@@ -81,7 +82,6 @@ def test_bucket_size_edges():
 
 
 def test_bucket_for_more_shards_than_rows():
-    from repro.serve import bucket_for
     # n_shards > n: the bucket must still cover every shard, or the
     # data axis silently drops to replication
     assert bucket_for(3, 8, 16) == 16
@@ -90,6 +90,37 @@ def test_bucket_for_more_shards_than_rows():
     for n in range(1, 8):
         b = bucket_for(n, 2, 12)
         assert b >= 12 and b % 12 == 0
+
+
+def _imported_modules(path):
+    """Every absolute module a file imports, lazy imports included."""
+    for node in ast.walk(ast.parse(path.read_text())):
+        if isinstance(node, ast.Import):
+            yield from (a.name for a in node.names)
+        elif isinstance(node, ast.ImportFrom) and node.level == 0:
+            yield node.module
+            yield from (f"{node.module}.{a.name}" for a in node.names)
+
+
+def test_lower_layers_do_not_import_serve():
+    """core and kernels sit below serve: the only upward import left is
+    the engine's residency metering; tune takes the bucket policy from
+    repro.dist.buckets, never from the batcher."""
+    src = pathlib.Path(__file__).resolve().parents[1] / "src" / "repro"
+    found = []
+    for layer, banned in (("core", "repro.serve"), ("kernels", "repro.serve"),
+                          ("tune", "repro.serve.batcher")):
+        files = sorted((src / layer).rglob("*.py"))
+        assert files
+        for f in files:
+            for mod in _imported_modules(f):
+                if mod == banned or mod.startswith(banned + "."):
+                    allowed = f == src / "core" / "engine.py" and (
+                        mod == "repro.serve.residency"
+                        or mod.startswith("repro.serve.residency."))
+                    if not allowed:
+                        found.append((str(f.relative_to(src)), mod))
+    assert found == []
 
 
 def test_deadline_flush_under_concurrent_submitters(tmp_path):

@@ -349,14 +349,17 @@ def test_residency_prefetch_warms_bundle(tmp_path):
 
 
 # ----------------------------------------------------- end-to-end submit ---
-def test_tenant_submit_roundtrip_and_latency_accounting(tmp_path):
+@pytest.mark.parametrize("route", ["flush", "pod_flush"])
+def test_tenant_submit_roundtrip_and_latency_accounting(tmp_path, route):
+    """The outcomes land on the tenants through either entry point of the
+    batcher's one batch routine (a single-process pod_flush included)."""
     board = TenantBoard([TenantSpec("a", tier="latency", weight=2.0),
                          TenantSpec("b")])
     queue = ServeQueue(FlushPolicy(max_batch_rows=256), tenancy=board)
     mp = _bundle(tmp_path)
     fa = queue.submit(mp, _rows(8, seed=1), tenant="a")
     fb = queue.submit(mp, _rows(8, seed=2), tenant="b")
-    queue.flush()
+    getattr(queue, route)()
     fa.result(30), fb.result(30)
     snap = board.snapshot()
     # the second submit rebinds the shared key, so request->tenant
