@@ -29,7 +29,7 @@ from __future__ import annotations
 
 import functools
 import time
-from typing import Callable, Dict, Optional, Tuple
+from typing import Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -51,6 +51,20 @@ _BRIDGES = _m.counter(
     "region data bridges taken, by direction and by path: the caller's "
     "array itself (identity) or a bridge program",
     ("region", "direction", "path"))
+
+
+class Landing(NamedTuple):
+    """Where an identity output wants its served rows: a jax.Array of
+    ``shape`` on ``device``, committed as ``committed`` says, of the
+    dtype named ``dtype`` (None: the served rows' own), as
+    :meth:`MLRegion._rows_out`'s put of host rows gives them.
+    ``ServeQueue.submit(..., land=)`` carries it to the batcher, which
+    lands the batch that way where it can."""
+
+    device: jax.Device
+    committed: bool
+    shape: Tuple[int, ...]
+    dtype: Optional[str]
 
 
 def _is_traced(*arrays):
@@ -236,7 +250,9 @@ class MLRegion:
         program's template; host rows (row views of the batcher's pooled
         buffer) take one copying put to where the program would have run:
         the device of the template when the caller committed it, else the
-        default device (a pure output's program reads no caller array)."""
+        default device (a pure output's program reads no caller array).
+        Rows the batch already landed there (:meth:`_landing`) come as
+        that jax.Array, at that shape and dtype, and skip the put."""
         ident = self._identity_out
         with TRACER.child("region.bridge_out", trace, cat="region"):
             if ident is not None:
@@ -244,7 +260,7 @@ class MLRegion:
                 t = arrays.get(name)  # None: a pure output
                 host = isinstance(Y, np.ndarray)
                 homes = _committed_devices(t) if host else ()
-                if (t is None or np.shape(t) == shape) and len(homes) <= 1:
+                if _fits(t, shape) and len(homes) <= 1:
                     Y = Y.reshape(shape)
                     if host:
                         Y = jax.device_put(Y, next(iter(homes), None),
@@ -256,6 +272,24 @@ class MLRegion:
                     return {name: Y}
             _BRIDGES.inc(region=self.name, direction="out", path="program")
             return self._bridge_from_jit(Y, arrays)
+
+    def _landing(self, arrays: dict) -> Optional[Landing]:
+        """Where :meth:`_rows_out` would put host rows through the identity
+        output, as the batch can land them on the device instead; None
+        where it would run the bridge program."""
+        ident = self._identity_out
+        if ident is None:
+            return None
+        name, shape = ident
+        t = arrays.get(name)
+        homes = _committed_devices(t)
+        if not _fits(t, shape) or len(homes) > 1:
+            return None
+        dtype = None if t is None else \
+            jax.dtypes.canonicalize_dtype(t.dtype).name
+        if homes:
+            return Landing(next(iter(homes)), True, shape, dtype)
+        return Landing(_default_device(), False, shape, dtype)
 
     def _call_span(self, body, arrays: dict):
         """``body(arrays, trace, args)`` inside this eager call's
@@ -330,7 +364,8 @@ class MLRegion:
         # as a retrain between submit and flush
         eng = InferenceEngine.cached(self.model_path, trace)
         Xb = self._rows_in(eng, arrays, trace, args)
-        fut = self.serving.submit(self.model_path, Xb, trace=trace)
+        fut = self.serving.submit(self.model_path, Xb, trace=trace,
+                                  land=self._landing(arrays))
         if SHADOW.enabled and SHADOW.sample():
             self._shadow_submit(arrays, rows=int(Xb.shape[0]), future=fut)
         return AsyncRegionResult(self, arrays, future=fut)
@@ -446,6 +481,18 @@ def _identity(side: dict):
     (name, (functor, ranges)), = side.items()
     shape = TensorMap(functor, None, ranges).identity_shape()
     return None if shape is None else (name, shape)
+
+
+def _fits(t, shape) -> bool:
+    """An identity output lands as ``t``'s whole array (or as a pure
+    output, ``t`` None) only at the identity shape."""
+    return t is None or np.shape(t) == shape
+
+
+def _default_device():
+    """The device an uncommitted ``jax.device_put`` lands on."""
+    d = jax.config.jax_default_device
+    return d if isinstance(d, jax.Device) else jax.devices(d)[0]
 
 
 def _committed_devices(tree) -> set:

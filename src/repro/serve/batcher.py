@@ -4,8 +4,10 @@ The queue hands the batcher a FIFO run of requests for one bundle path;
 the batcher concatenates their rows, dispatches them through the
 engine's :meth:`InferenceEngine.apply_batched` (which pads to the
 power-of-two bucket, places the batch over the ``data`` axis of the
-active mesh, and slices the padding back off), then scatters per-request
-row slices into the callers' futures.
+active mesh, and slices the padding back off), then lands the output
+(split on its device where every request asked for device rows, else
+read back to a host buffer) and scatters per-request rows into the
+callers' futures.
 
 Row-wise surrogates make this exact rather than approximate: each output
 row depends only on its input row, so a request's rows come back
@@ -17,6 +19,7 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
+import math
 import time
 from typing import Callable, List, Optional, Tuple
 
@@ -47,6 +50,10 @@ _SPLITS = _metrics.counter(
 _NONFINITE = _metrics.counter(
     "repro_resilience_nonfinite_total",
     "output rows screened as NaN/Inf before scatter", ("key",))
+_LANDED = _metrics.counter(
+    "repro_batch_land_total",
+    "served batches by where their rows landed: split on the device, or "
+    "read back to a host buffer", ("key", "route"))
 
 
 class NonFiniteOutput(RuntimeError):
@@ -106,6 +113,9 @@ class Batcher:
         self.min_bucket = min_bucket
         self.scratch = scratch or ScratchPool()
         self.retry = retry or DEFAULT_RETRY
+        # one compiled split per batch layout (see _split), shared by
+        # every region that serves through this batcher
+        self._splits = {}
         # ServeQueue attaches its TenantBoard here so per-request
         # outcomes (served rows + latencies, drops) land on the tenant
         # that submitted them; None = tenancy-free queue, zero overhead
@@ -211,6 +221,49 @@ class Batcher:
                 f"(ServeQueue.pod_flush / Batcher.dispatch_pod) or "
                 f"gather the array before landing it.")
         return out
+
+    @staticmethod
+    def _device_landing(requests, Y):
+        """The one landing (``repro.core.region.Landing``: device,
+        committed, shape, dtype) every request asked for, when ``Y``
+        can be split into it on the device: equal request sizes, ``Y``
+        fully addressable on that one device and committed alike, and
+        each request's rows of the landing's size.  Else None: the batch
+        is read back to the host."""
+        land = requests[0].land if requests else None
+        if land is None or not isinstance(Y, jax.Array):
+            return None
+        n = requests[0].n
+        if any(r.land != land or r.n != n for r in requests):
+            return None
+        if (not Y.is_fully_addressable or Y.devices() != {land.device}
+                or Y.committed != land.committed
+                or math.prod(land.shape) != n * math.prod(Y.shape[1:])):
+            return None
+        return land
+
+    def _split(self, Y, land, count: int, n: int, start: int):
+        """``count`` requests' rows of ``n`` from row ``start`` of ``Y``,
+        each at the landing's shape and dtype, and one flag a request
+        saying its rows are all finite: one compiled program, cached per
+        layout, whose outputs stay where ``Y`` is."""
+        layout = (Y.shape, Y.dtype, start, count, n, land)
+        fn = self._splits.get(layout)
+        if fn is None:
+            inexact = jnp.issubdtype(Y.dtype, jnp.inexact)
+
+            def split(y):
+                y = y[start:start + count * n]
+                parts = tuple(
+                    p.reshape(land.shape).astype(
+                        y.dtype if land.dtype is None else land.dtype)
+                    for p in jnp.split(y, count))
+                ok = (jnp.isfinite(y.reshape(count, -1)).all(axis=1)
+                      if inexact else jnp.ones((count,), bool))
+                return parts, ok
+
+            fn = self._splits[layout] = jax.jit(split)
+        return fn(Y)
 
     def _fail_all(self, b: _Batch, exc: Exception) -> None:
         for r in b.requests:
@@ -340,7 +393,7 @@ class Batcher:
             self._fail_all(b, e)
             return
         n = sum(r.n for r in requests)
-        X = Y = None
+        X = Y = parts = None
         last_exc: Optional[Exception] = None
         for attempt in range(b.attempts):
             try:
@@ -363,16 +416,28 @@ class Batcher:
                         Y = eng.apply_batched(X, min_bucket=self.min_bucket,
                                               prepadded=prepadded)
                     Y = jax.block_until_ready(Y)
-                # one device->host gather for the whole mega-batch:
-                # scattering zero-copy numpy row views is ~1000x cheaper
-                # than slicing a mesh-sharded array once per caller (each
-                # such slice is a cross-device gather of its own)
+                # land the batch.  Device route: when every request wants
+                # its rows on Y's device, one program splits them there
+                # and only a flag a request comes back.  Host route: one
+                # device->host gather for the whole mega-batch; scattering
+                # zero-copy numpy row views is ~1000x cheaper than slicing
+                # a mesh-sharded array once per caller (each such slice is
+                # a cross-device gather of its own)
+                land = self._device_landing(requests, Y)
+                route = "host" if land is None else "device"
                 with tr.span("batch.to_host", cat="batch",
-                             args={"key": key, "batch": bid}):
-                    Y = self._to_host(Y, rows=b.rows)
+                             args={"key": key, "batch": bid,
+                                   "route": route}):
+                    if land is None:
+                        Y = self._to_host(Y, rows=b.rows)
+                    else:
+                        parts, ok = self._split(
+                            Y, land, len(requests), requests[0].n,
+                            b.rows[0] if b.rows else 0)
+                        ok = np.array(ok)
                 break
             except Exception as e:
-                Y, last_exc = None, e
+                Y, parts, last_exc = None, None, e
                 tr.instant("batch.error", cat="batch",
                            args={"key": key, "batch": bid,
                                  "attempt": attempt, "error": repr(e)})
@@ -396,12 +461,21 @@ class Batcher:
                 return
             self._fail_all(b, last_exc)
             return
+        _LANDED.inc(1, key=key, route=route)
         if FAULTS.enabled and requests:
             rule = FAULTS.fire("batcher.scatter", key=key)
             if rule is not None and rule.mode in ("nan", "inf"):
-                Y = np.array(Y)  # writable copy on the injected path only
-                Y[:requests[0].n] = rule.value
-        bad = self._screen_nonfinite(requests, Y)
+                # poison the first request's rows where they landed, on
+                # the injected path only
+                if parts is None:
+                    Y = np.array(Y)  # writable copy
+                    Y[:requests[0].n] = rule.value
+                else:
+                    parts = (jnp.full_like(parts[0], rule.value),) + \
+                        parts[1:]
+                    ok[0] = bool(jnp.isfinite(parts[0]).all())
+        bad = (self._screen_nonfinite(requests, Y) if parts is None
+               else tuple(int(i) for i in np.flatnonzero(~ok)))
         t1 = time.monotonic()
         off = 0
         lats = []
@@ -422,7 +496,8 @@ class Batcher:
                     bad_rows += r.n
                     off += r.n
                     continue
-                r.future.set_result(Y[off:off + r.n])
+                r.future.set_result(Y[off:off + r.n] if parts is None
+                                    else parts[i])
                 off += r.n
                 lats.append(t1 - r.t_enqueue)
                 if traced:

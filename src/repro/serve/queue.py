@@ -122,15 +122,16 @@ class ServeFuture:
 
 class _Request:
     __slots__ = ("key", "x", "n", "future", "t_enqueue", "ctx", "trace",
-                 "tenant")
+                 "tenant", "land")
 
     def __init__(self, key, x, n, future, t_enqueue, ctx, trace=None,
-                 tenant=None):
+                 tenant=None, land=None):
         self.key, self.x, self.n = key, x, n
         self.future, self.t_enqueue = future, t_enqueue
         self.ctx = ctx  # submitter's ShardCtx: sharding is thread-local
         self.trace = trace  # obs trace id, minted at submit, rides along
         self.tenant = tenant  # tenancy id (None on tenancy-free queues)
+        self.land = land  # core.region.Landing, or None: host rows
 
 
 class _StatsGate:
@@ -326,8 +327,14 @@ class ServeQueue:
 
     # ----------------------------------------------------------- submit ---
     def submit(self, key: str, rows, *, tenant: Optional[str] = None,
-               trace: Optional[str] = None) -> ServeFuture:
+               trace: Optional[str] = None, land=None) -> ServeFuture:
         """Queue ``rows`` ([n, ...features], n >= 1) for bundle ``key``.
+
+        The future resolves to the request's output rows: a row view of
+        a host buffer, or, with ``land`` given (a
+        ``repro.core.region.Landing``), a jax.Array placed as it says
+        when the batch can be split on its device; host rows otherwise
+        (README.md, "Landing a batch").
 
         With a tenancy board attached, ``tenant`` names the submitting
         tenant (default tenant otherwise): admission charges its token
@@ -341,15 +348,16 @@ class ServeQueue:
         is its ``queue.submit`` span.
         """
         if not TRACER.enabled:
-            return self._submit(key, rows, tenant, None, None)
+            return self._submit(key, rows, tenant, None, None, land)
         trace = trace or TRACER.new_trace_id()
         args = {"key": key}
         with TRACER.span("queue.submit", cat="queue", trace=trace,
                          args=args):
-            return self._submit(key, rows, tenant, trace, args)
+            return self._submit(key, rows, tenant, trace, args, land)
 
     def _submit(self, key: str, rows, tenant: Optional[str],
-                trace: Optional[str], args: Optional[dict]) -> ServeFuture:
+                trace: Optional[str], args: Optional[dict],
+                land) -> ServeFuture:
         from repro.dist.sharding import current_ctx
         board = self.tenancy
         if board is not None:
@@ -370,7 +378,8 @@ class ServeQueue:
         fut = ServeFuture(self, key)
         t_sub = time.monotonic()
         fut.trace = trace  # shadow scoring rides the same id
-        req = _Request(key, x, n, fut, t_sub, current_ctx(), trace, tenant)
+        req = _Request(key, x, n, fut, t_sub, current_ctx(), trace, tenant,
+                       land)
         deadline = t_sub + self.policy.block_timeout_s
         while True:
             admitted, drain_inline, flush_inline = False, False, False

@@ -19,7 +19,7 @@ from repro.core.tensor_map import TensorMap
 from repro.nn import MLP
 from repro.nn.serialize import save_model
 from repro.obs import default_registry
-from repro.serve import FlushPolicy, ServeQueue
+from repro.serve import Batcher, FlushPolicy, ServeQueue
 
 N = 6
 QUICKSTART_IN = "ifnctr: [i, j, 0:5] = ([i-1,j],[i+1,j],[i,j-1:j+2])"
@@ -259,32 +259,68 @@ def test_numpy_input_is_copied_before_the_flush(tmp_path):
     assert np.array_equal(np.asarray(got), want)
 
 
-def test_identity_output_outlives_the_reused_scratch_buffer(tmp_path):
-    """The output's one put copies the served rows: the batcher's pooled
-    buffer they came from serves the next batch, and the first output
-    keeps its values."""
-    case = IDENTITY
+# two identity regions on one bundle: 6 rows a call, and 5
+SHORT = dict(IDENTITY, name="short", arrays={"x": (N - 1, 5)},
+             inputs={"x": ("a: [i, 0:5] = ([i, 0:5])", {"i": (0, N - 1)})},
+             out=("y", "b: [i, 0:1] = ([i, 0:1])", {"i": (0, N - 1)}))
+
+
+def _lands(key: str) -> dict:
+    snap = default_registry().collect().get("repro_batch_land_total",
+                                            {"values": []})
+    return {v["labels"]["route"]: v["value"] for v in snap["values"]
+            if v["labels"]["key"] == key}
+
+
+def _outlives_the_next_batch(tmp_path, cases, route):
+    """Serve one batch of ``cases``' regions, then another: the first
+    batch's outputs keep their values.  Returns the scratch pool's
+    takes and hits during the second batch."""
     path = _bundle(tmp_path, 5, 1, "m")
     q = ServeQueue(FlushPolicy(max_batch_rows=1 << 30))
-    reg = _region(case, "infer_async", path, q)
+    regs = [_region(c, "infer_async", path, q) for c in cases]
     scratch = q._batcher.scratch
 
     def served(seed):
-        handles = [reg(**_arrays(case, seed + k)) for k in range(2)]
+        handles = [r(**_arrays(c, seed + k))
+                   for k, (r, c) in enumerate(zip(regs, cases))]
         q.flush(path)
         return [h.result(30)["y"] for h in handles]
 
+    before = _lands(path)
     first = served(10)
+    assert _delta(_lands(path), before) == {route: 1}
     kept = [np.array(y) for y in first]
     gc.collect()  # no view of the first batch's buffer is left
-    hits = scratch.stats()["hits"]
+    pool = scratch.stats()
     second = served(20)
-    assert scratch.stats()["hits"] > hits
+    takes = {k: v - pool[k] for k, v in scratch.stats().items()
+             if k in ("hits", "misses")}
     assert not any(np.array_equal(a, np.asarray(b))
                    for a, b in zip(kept, second))
     for y, k in zip(first, kept):
         assert isinstance(y, jax.Array)
         assert np.array_equal(np.asarray(y), k)
+    return takes["hits"] + takes["misses"], takes["hits"]
+
+
+def test_identity_output_outlives_the_reused_scratch_buffer(tmp_path):
+    """The output's one put copies the served rows: the batcher's pooled
+    buffer they came from (the host route, as unequal requests take)
+    serves the next batch, and the first output keeps its values."""
+    # the next batch takes a pooled buffer for the gather and one for the
+    # read-back; a host-to-device copy of the first batch may still hold
+    # its view of one for a moment, so one of the two takes may miss
+    takes, hits = _outlives_the_next_batch(tmp_path, [IDENTITY, SHORT],
+                                           "host")
+    assert takes == 2 and hits >= 1
+
+
+def test_device_landed_output_outlives_the_next_batch(tmp_path):
+    """Equal requests land on the device: the next batch takes only the
+    gather's pooled buffer, and the first outputs keep their values."""
+    assert _outlives_the_next_batch(tmp_path, [IDENTITY, IDENTITY],
+                                    "device") == (1, 1)
 
 
 def test_first_identity_call_compiles_no_bridge(tmp_path):
@@ -353,6 +389,186 @@ def test_identity_output_lands_where_the_program_would(tmp_path):
     output written into the caller's committed array lands beside it."""
     here = os.path.dirname(os.path.abspath(__file__))
     code = _DEVICES.format(src=os.path.join(os.path.dirname(here), "src"),
+                           tests=here)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, env=env, timeout=300)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert out.stdout.strip().endswith("ok")
+
+
+# ------------------------------------------------- landing a batch ---
+class _HostBatcher(Batcher):
+    """A batcher that reads every batch back to the host."""
+
+    @staticmethod
+    def _device_landing(requests, Y):
+        return None
+
+
+def _served(q, regs, seed):
+    """One batch of one call a region; the raw served rows and the
+    region outputs."""
+    handles = [r(**_arrays(IDENTITY, seed + k)) for k, r in enumerate(regs)]
+    q.flush(regs[0].model_path)
+    rows = [h._future.result(30) for h in handles]
+    return rows, [h.result(30)["y"] for h in handles]
+
+
+def test_device_route_serves_the_host_routes_rows(tmp_path):
+    """Equal requests that ask for device rows land on the device, one
+    count a batch, bit for bit the host route's rows; each future holds
+    a jax.Array on the default device, uncommitted, as the put gives."""
+    path = _bundle(tmp_path, 5, 1, "m")
+    pol = FlushPolicy(max_batch_rows=1 << 30)
+    dev_q = ServeQueue(pol)
+    host_q = ServeQueue(pol, batcher=_HostBatcher())
+    dev_regs = [_region(IDENTITY, "infer_async", path, dev_q)
+                for _ in range(3)]
+    host_regs = [_region(IDENTITY, "infer_async", path, host_q)
+                 for _ in range(3)]
+    for seed in (1, 5):
+        before = _lands(path)
+        rows, got = _served(dev_q, dev_regs, seed)
+        assert _delta(_lands(path), before) == {"device": 1}
+        host_rows, want = _served(host_q, host_regs, seed)
+        assert _delta(_lands(path), before) == {"device": 1, "host": 1}
+        assert all(isinstance(y, np.ndarray) for y in host_rows)
+        for y, g, w in zip(rows, got, want):
+            assert isinstance(y, jax.Array)
+            assert y.shape == (N, 1) and y.dtype == jnp.float32
+            assert g is y  # the region's output is the landed array
+            assert g.devices() == w.devices() == {jax.devices()[0]}
+            assert g.committed == w.committed is False
+            assert np.array_equal(np.asarray(g), np.asarray(w))
+
+
+def test_batches_of_unequal_or_plain_requests_land_on_the_host(tmp_path):
+    """Unequal request sizes, a plain submit without ``land`` among
+    region calls, or a plain submit alone: the host route."""
+    path = _bundle(tmp_path, 5, 1, "m")
+    q = ServeQueue(FlushPolicy(max_batch_rows=1 << 30))
+    reg, short = (_region(c, "infer_async", path, q)
+                  for c in (IDENTITY, SHORT))
+    x = _arrays(IDENTITY)["x"]
+    for calls in ([lambda: reg(x=x), lambda: short(**_arrays(SHORT))],
+                  [lambda: reg(x=x), lambda: q.submit(path, x)],
+                  [lambda: q.submit(path, x), lambda: q.submit(path, x)]):
+        before = _lands(path)
+        handles = [c() for c in calls]
+        q.flush(path)
+        assert _delta(_lands(path), before) == {"host": 1}
+        for h in handles:
+            y = h.result(30)
+            if isinstance(y, dict):  # a region's output, put by the region
+                assert isinstance(y["y"], jax.Array)
+                assert isinstance(h._future.result(30), np.ndarray)
+            else:
+                assert isinstance(y, np.ndarray)
+
+
+def test_each_batch_layout_compiles_its_split_once(tmp_path):
+    """A second layout (more callers) compiles one more split; repeated
+    batches of either layout compile nothing."""
+    compiles = default_registry().counter(
+        "repro_xla_compile_total", "XLA backend compiles in this process")
+    path = _bundle(tmp_path, 5, 1, "m")
+    q = ServeQueue(FlushPolicy(max_batch_rows=1 << 30))
+    splits = q._batcher._splits
+    regs = [_region(IDENTITY, "infer_async", path, q) for _ in range(3)]
+    _served(q, regs[:2], 0)
+    assert len(splits) == 1
+    _served(q, regs, 0)
+    assert len(splits) == 2
+    before = compiles.value()
+    for seed in range(3):
+        _served(q, regs[:2], seed)
+        _served(q, regs, seed)
+    assert compiles.value() == before
+    assert len(splits) == 2
+    assert all(fn._cache_size() == 1 for fn in splits.values())
+
+
+_LANDING = r"""
+import os, sys
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+sys.path.insert(0, {src!r})
+sys.path.insert(0, {tests!r})
+import jax, numpy as np
+from test_region_bridge import (_CASES, _arrays, _bundle, _lands, _region,
+                                _HostBatcher)
+from repro.dist.sharding import use_mesh
+from repro.launch.mesh import make_local_mesh
+from repro.serve import FlushPolicy, ServeQueue
+import pathlib, tempfile
+tmp = pathlib.Path(tempfile.mkdtemp())
+pol = FlushPolicy(max_batch_rows=1 << 30)
+dev = jax.devices()[1]
+cases = {{c["name"]: c for c in _CASES}}
+
+
+def serve(q, case, path, arrays, calls=2):
+    regs = [_region(case, "infer_async", path, q) for _ in range(calls)]
+    before = _lands(path)
+    hs = [r(**arrays) for r in regs]
+    q.flush(path)
+    outs = [h.result(30)[case["out"][0]] for h in hs]
+    after = _lands(path)
+    return outs, {{k: v - before.get(k, 0) for k, v in after.items()
+                  if v != before.get(k, 0)}}
+
+
+# a field written back into the caller's array committed on the second
+# device: one request lands there, committed, as the put would
+case = cases["field"]
+path = _bundle(tmp, 4, 4, name="field")
+arrays = {{k: jax.device_put(x, dev) for k, x in _arrays(case).items()}}
+got, lands = serve(ServeQueue(pol), case, path, arrays, calls=1)
+assert lands == {{"device": 1}}, lands
+want, lands = serve(ServeQueue(pol, batcher=_HostBatcher()), case, path,
+                    arrays, calls=1)
+assert lands == {{"host": 1}}, lands
+assert got[0].devices() == want[0].devices() == {{dev}}
+assert got[0].committed and want[0].committed
+assert np.array_equal(np.asarray(got[0]), np.asarray(want[0]))
+
+# inputs committed to the second device, a pure output put to the
+# default one: the batch lands on the host
+case = cases["identity"]
+path = _bundle(tmp, 5, 1, name="identity")
+arrays = {{k: jax.device_put(x, dev) for k, x in _arrays(case).items()}}
+got, lands = serve(ServeQueue(pol), case, path, arrays, calls=1)
+assert lands == {{"host": 1}}, lands
+assert got[0].devices() == {{jax.devices()[0]}} and not got[0].committed
+
+# inputs committed to the default device: the served rows are committed
+# there, the put's are not, so the batch lands on the host
+arrays = {{k: jax.device_put(x, jax.devices()[0])
+           for k, x in _arrays(case).items()}}
+got, lands = serve(ServeQueue(pol), case, path, arrays, calls=1)
+assert lands == {{"host": 1}}, lands
+assert got[0].devices() == {{jax.devices()[0]}} and not got[0].committed
+
+# a 1x2 data mesh: the output is sharded, so the batch lands on the host
+with use_mesh(make_local_mesh()):
+    q = ServeQueue(pol)
+    got, lands = serve(q, case, path, _arrays(case))
+assert lands == {{"host": 1}}, lands
+want, _ = serve(ServeQueue(pol), case, path, _arrays(case))
+for g, w in zip(got, want):
+    assert g.devices() == w.devices() and g.committed == w.committed
+    assert np.array_equal(np.asarray(g), np.asarray(w))
+print("ok")
+"""
+
+
+def test_batches_land_as_the_put_would_across_devices(tmp_path):
+    """On two virtual devices: rows committed to the second device land
+    there, committed; a requested device other than the output's, an
+    output committed where the put's would not be, or an output sharded
+    over a 1x2 mesh, takes the host route."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    code = _LANDING.format(src=os.path.join(os.path.dirname(here), "src"),
                            tests=here)
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,

@@ -17,7 +17,9 @@ from hypothesis import strategies as st
 from repro.core import approx_ml, tensor_functor
 from repro.launch import multihost
 from repro.nn import MLP
+from repro.core.region import Landing
 from repro.nn.serialize import save_model
+from repro.obs import default_registry
 from repro.obs.quality import SHADOW
 from repro.resilience import (BREAKERS, FAULTS, BreakerPolicy,
                               CircuitBreaker, FaultInjector, InjectedFault,
@@ -233,20 +235,38 @@ ROUTES = {"flush": lambda q, key: q.flush(key),
           "pod_flush": lambda q, key: q.pod_flush(key)}
 
 
-@pytest.mark.parametrize("route", sorted(ROUTES))
-def test_nonfinite_screening_isolates_poisoned_request(route):
+def _lands(key: str) -> dict:
+    snap = default_registry().collect().get("repro_batch_land_total",
+                                            {"values": []})
+    return {v["labels"]["route"]: v["value"] for v in snap["values"]
+            if v["labels"]["key"] == key}
+
+
+# each entry point, with host rows and (equal requests that ask for
+# device rows) with rows landed on the device
+@pytest.mark.parametrize("route,device", [
+    pytest.param(r, d, id=r + ("-device" if d else ""))
+    for d in (False, True) for r in sorted(ROUTES)])
+def test_nonfinite_screening_isolates_poisoned_request(route, device):
     eng = _StubEngine()
     q = _queue(eng)
     FAULTS.configure("batcher.scatter:nan:n=1")
-    xa, xb = _rows(3, seed=4), _rows(2, seed=5)
-    fa = q.submit("k", xa)
-    fb = q.submit("k", xb)
+    land = Landing(jax.devices()[0], False, (3, 1), None) if device \
+        else None
+    xa, xb = _rows(3, seed=4), _rows(3 if device else 2, seed=5)
+    before = _lands("k")
+    fa = q.submit("k", xa, land=land)
+    fb = q.submit("k", xb, land=land)
     ROUTES[route](q, "k")
+    after = _lands("k")
+    assert {k: v - before.get(k, 0) for k, v in after.items()
+            if v != before.get(k, 0)} == {"device" if device else "host": 1}
     # the injected NaN lands on the first request's rows only
     with pytest.raises(NonFiniteOutput):
         fa.result(5)
-    np.testing.assert_allclose(np.asarray(fb.result(5)), xb[:, :1] * 2.0,
-                               rtol=1e-6)
+    got = fb.result(5)
+    assert isinstance(got, jax.Array if device else np.ndarray)
+    np.testing.assert_allclose(np.asarray(got), xb[:, :1] * 2.0, rtol=1e-6)
     snap = q.stats("k").snapshot()
     assert snap["requests_failed"] == 1 and snap["rows_failed"] == 3
     assert snap["batches"] == 1  # the clean remainder still counts
